@@ -74,27 +74,35 @@ __all__ = (["DistArray", "SparseDistArray", "MaskedDistArray", "TileExtent",
 
 def initialize(argv=None):
     """Parity with the reference's ``spartan.initialize()`` (SURVEY.md
-    §3.1): parse flags, bring up the multi-host control plane when a
-    cluster environment is present (``jax.distributed`` plays the
-    reference master's registration/barrier role — SURVEY.md §2.7;
-    no-op standalone), enable the persistent compilation cache when
-    configured, and install the ambient mesh. The whole master/worker
-    bring-up otherwise collapses to mesh construction."""
+    §3.1): parse flags, place JAX's persistent compilation cache
+    (:func:`_compile_cache_dir`), bring up the multi-host control plane
+    when a cluster environment is present (``jax.distributed`` plays
+    the reference master's registration/barrier role — SURVEY.md §2.7;
+    no-op standalone), and install the ambient mesh. The whole
+    master/worker bring-up otherwise collapses to mesh construction."""
     rest = FLAGS.parse_args(argv)
-    cache_dir = getattr(FLAGS, "compilation_cache_dir", "")
-    if cache_dir:
-        # XLA programs (incl. the ~2-min Pallas-in-loop sparse
-        # compiles, docs/BENCH.md) persist across processes — the
-        # disk-level twin of the in-process structural compile cache
-        import jax
+    import jax
 
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        # jax's own persistence floor (min_compile_time 1s) is left
-        # untouched — users tune it via jax config / env themselves
+    cache_dir = _compile_cache_dir()
+    if cache_dir is not None:
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     resilience.faults.install_from_flags()  # FLAGS.fault_inject chaos
     _mesh.initialize_distributed()  # no-op unless COORDINATOR/SLURM env
     _mesh.get_mesh()
     return rest
+
+
+def _compile_cache_dir():
+    """Where compiled XLA programs persist across processes: None when
+    ``JAX_COMPILATION_CACHE_DIR`` is set (JAX read it already — the
+    caller placed the cache), else ``<checkout>/.jax_cache``. A fixed
+    path: the cache key includes it, so a moving directory never hits."""
+    import os
+
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(checkout, ".jax_cache")
 
 
 def ledger(validate=False):

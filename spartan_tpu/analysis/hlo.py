@@ -71,6 +71,11 @@ _PAIRS_RX = re.compile(r"source_target_pairs=\{([0-9,{} ]*)\}")
 _SCOPE_RX = re.compile(r"__sg_([0-9a-f]{4,16})")
 _OPNAME_RX = re.compile(r'op_name="([^"]*)"')
 _SOURCE_RX = re.compile(r'source_file="([^"]*)"(?:\s+source_line=(\d+))?')
+# JAX 0.9 metadata names a frame (stack_frame_id=N) of the module's
+# StackFrames -> FileLocations -> FileNames tables instead
+_FRAME_ID_RX = re.compile(r"stack_frame_id=(\d+)")
+_TABLE_ROW_RX = re.compile(r"^(\d+) (.*)$")
+_FRAME_TABLES = ("FileNames", "FileLocations", "StackFrames")
 
 # module-header donation record: input_output_alias={ {1}: (0, {},
 # may-alias), ... } — the tuple's first element is the PARAMETER number
@@ -177,12 +182,41 @@ class CollectiveOp:
                 f"~{self.bytes_moved:.0f}B{who}>")
 
 
+def _frame_sources(hlo_text: str) -> Dict[int, str]:
+    """``stack_frame_id`` -> ``"file:line"`` of that (innermost) frame,
+    from the module's frame tables."""
+    tables: Dict[str, Dict[int, str]] = {}
+    cur = None
+    for line in hlo_text.splitlines():
+        s = line.strip()
+        if s in _FRAME_TABLES:
+            cur = tables.setdefault(s, {})
+            continue
+        m = _TABLE_ROW_RX.match(s) if cur is not None else None
+        if m is None:
+            cur = None
+            continue
+        cur[int(m.group(1))] = m.group(2)
+
+    def ref(row: str, field: str) -> int:
+        m = re.search(rf"\b{field}=(\d+)", row)
+        return int(m.group(1)) if m else -1
+
+    files = {k: v.strip('"') for k, v in tables.get("FileNames", {}).items()}
+    locs = {k: f"{files.get(ref(v, 'file_name_id'), '?')}:{ref(v, 'line')}"
+            for k, v in tables.get("FileLocations", {}).items()}
+    return {k: locs[ref(v, "file_location_id")]
+            for k, v in tables.get("StackFrames", {}).items()
+            if ref(v, "file_location_id") in locs}
+
+
 def parse_collectives(hlo_text: str) -> List[CollectiveOp]:
     """Every collective instruction of a compiled module, in program
     order. ``-done`` halves are skipped (their ``-start`` was counted);
     computation definitions (``to_apply`` bodies) contain no collective
     opcodes, so a line scan is exact."""
     out: List[CollectiveOp] = []
+    frames = _frame_sources(hlo_text)
     for line in hlo_text.splitlines():
         m = _INSTR_RX.search(line)
         if m is None:
@@ -205,6 +239,10 @@ def parse_collectives(hlo_text: str) -> List[CollectiveOp]:
             source = srcm.group(1)
             if srcm.group(2):
                 source += f":{srcm.group(2)}"
+        else:
+            fm = _FRAME_ID_RX.search(tail)
+            if fm is not None:
+                source = frames.get(int(fm.group(1)))
         out.append(CollectiveOp(kind, result_bytes, operand_bytes,
                                 _group_size(tail), scope, op_name,
                                 source))

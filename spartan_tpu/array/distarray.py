@@ -531,7 +531,7 @@ class DistArray:
         """Apply a shape-preserving jax-traceable fn to every shard
         independently (owner-computes, no communication) — the analogue of
         ``foreach_tile`` (SURVEY.md §2.2) for traceable kernels."""
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         spec = self.tiling.spec()
         mapped = shard_map(fn, mesh=self.mesh, in_specs=(spec,),

@@ -189,7 +189,7 @@ def _build_sharded_spmv(mesh, n, x_ndim):
     over the entry axis merges the partials: exactly the reference's
     per-tile sparse kernel + reducer-merge (SURVEY.md §2.2
     sparse_update), lowered to segment_sum + psum over ICI."""
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     from ..parallel.mesh import AXIS_ROW
 
@@ -206,7 +206,7 @@ def _build_sharded_spmv(mesh, n, x_ndim):
 
 
 def _build_sharded_rsums(mesh, n):
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     from ..parallel.mesh import AXIS_ROW
 

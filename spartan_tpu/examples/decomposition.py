@@ -46,7 +46,7 @@ def tsqr(a) -> Tuple[np.ndarray, np.ndarray]:
     """Tall-skinny QR: local QR per row shard, tree-reduced R factors —
     the owner-computes algorithm the reference's per-tile QR performed,
     expressed as one shard_map program."""
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     from ..parallel import mesh as mesh_mod
 

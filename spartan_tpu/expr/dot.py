@@ -141,7 +141,7 @@ class DotShardMapExpr(Expr):
         return DotShardMapExpr(new_children[0], new_children[1])
 
     def _lower(self, env: Dict[int, Any]) -> Any:
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         mesh = mesh_mod.get_mesh()
         av = self.a.lower(env)

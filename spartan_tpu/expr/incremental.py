@@ -628,6 +628,8 @@ def _restrict(n: Any, box: TileExtent, memo: Dict,
             _pre=n.pre)
     elif isinstance(n, DotExpr):
         a, b = n.children()
+        if _split_mismatch(a, b):
+            raise Unsupported("restrict:dot-contraction-split")
         if a.ndim == 2 and b.ndim == 2:
             abox = TileExtent((box.ul[0], 0), (box.lr[0], a.shape[1]),
                               a.shape)
@@ -649,6 +651,24 @@ def _restrict(n: Any, box: TileExtent, memo: Dict,
         raise Unsupported(f"restrict:{type(n).__name__}")
     memo[key] = out
     return out
+
+
+def _split_mismatch(a: Any, b: Any) -> bool:
+    """Do the operands of ``a @ b`` split the contracted dim over
+    different mesh axes? The partitioner then picks the full plan's
+    per-shard partial sums from the operand shapes, and a restricted
+    dot may pick another split, which rounds differently."""
+    from ..parallel import mesh as mesh_mod
+
+    mesh = mesh_mod.get_mesh()
+
+    def split(axis):
+        names = axis if isinstance(axis, tuple) else (axis,)
+        return tuple(nm for nm in names
+                     if nm is not None and int(mesh.shape.get(nm, 1)) > 1)
+
+    return (split(a.out_tiling().axes[-1])
+            != split(b.out_tiling().axes[0]))
 
 
 def _restrict_bcast(c: Any, box: TileExtent,

@@ -138,7 +138,7 @@ class MapWithLocationExpr(Expr):
 
     def _lower(self, env: Dict[int, Any]) -> Any:
         import jax
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         from ..parallel import mesh as mesh_mod
 

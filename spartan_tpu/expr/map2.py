@@ -101,7 +101,7 @@ class ShardMap2Expr(Expr):
 
     def _lower(self, env: Dict[int, Any]) -> Any:
         from ..parallel import redistribute as redist_mod
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         mesh = mesh_mod.get_mesh()
         vals = []

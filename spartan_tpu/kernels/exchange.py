@@ -13,7 +13,8 @@ pass per destination:
 * the lane part is a one-hot permutation matmul on the MXU — exact
   for EVERY 32-bit pattern (NaN payloads included) because the value
   is split into two 16-bit halves, rolled as exact f32 integers, and
-  reassembled (a float matmul on raw bits would launder NaNs).
+  reassembled (a float matmul on raw bits would launder NaNs). The
+  bits travel as int32: Mosaic has no uint32 <-> float32 cast.
 
 Validity needs no kernel: ``t < counts[j]`` is an iota compare.
 """
@@ -43,9 +44,9 @@ def partition_pack(xs: jax.Array, starts: jax.Array,
     dt = xs.dtype
     mr = -(-m // LANE)                       # destination row blocks
     src_rows = -(-m // LANE) + mr + 1        # slice reach: start + m
-    xs_u = jax.lax.bitcast_convert_type(
-        jnp.zeros((src_rows * LANE,), dt).at[:m].set(xs), jnp.uint32)
-    xs2 = xs_u.reshape(src_rows, LANE)
+    xs_i = jax.lax.bitcast_convert_type(
+        jnp.zeros((src_rows * LANE,), dt).at[:m].set(xs), jnp.int32)
+    xs2 = xs_i.reshape(src_rows, LANE)
 
     def kernel(s_ref, c_ref, x_ref, out_ref):
         j = pl.program_id(0)
@@ -53,7 +54,7 @@ def partition_pack(xs: jax.Array, starts: jax.Array,
         a = s // LANE
         b = s % LANE
         x = x_ref[pl.ds(a, mr + 1), :]
-        hi = (x >> 16).astype(jnp.float32)
+        hi = ((x >> 16) & 0xFFFF).astype(jnp.float32)
         lo = (x & 0xFFFF).astype(jnp.float32)
         # P[c, l] = 1 iff c == (b + l) % 128: Y = X @ P rolls lanes
         # left by b; both halves are < 2**16, exact in f32 at HIGHEST
@@ -68,15 +69,14 @@ def partition_pack(xs: jax.Array, starts: jax.Array,
             lo, perm, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST)
-        y = ((yhi.astype(jnp.uint32) << 16)
-             | ylo.astype(jnp.uint32))
+        y = (yhi.astype(jnp.int32) << 16) | ylo.astype(jnp.int32)
         lane = jax.lax.broadcasted_iota(jnp.int32, (mr, LANE), 1)
         # element (r, l) of row j is xs[s + r*128 + l]: lane l came
         # from source row a+r when b+l < 128, else a+r+1 (the carry)
         yv = jnp.where(b + lane < LANE, y[:mr, :], y[1:mr + 1, :])
         t = (jax.lax.broadcasted_iota(jnp.int32, (mr, LANE), 0) * LANE
              + lane)
-        out_ref[:] = jnp.where(t < c_ref[j], yv, 0).astype(jnp.uint32)
+        out_ref[:] = jnp.where(t < c_ref[j], yv, 0)
 
     out = pl.pallas_call(
         kernel,
@@ -88,7 +88,7 @@ def partition_pack(xs: jax.Array, starts: jax.Array,
             ],
             out_specs=pl.BlockSpec((mr, LANE), lambda j, s, c: (j, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((p * mr, LANE), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((p * mr, LANE), jnp.int32),
         interpret=sel.interpret,
     )(starts.astype(jnp.int32), counts.astype(jnp.int32), xs2)
     out = jax.lax.bitcast_convert_type(out.reshape(p, mr * LANE), dt)

@@ -66,7 +66,7 @@ def bincount_sharded(ids: jax.Array, length: int,
     with :func:`bincount_block`, ``psum`` the count rows. Returns
     int32 (jnp.bincount parity; counts are exact in f32 to 2**24 and
     each shard holds far fewer entries than that)."""
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     mesh = mesh or mesh_mod.get_mesh()
     axis = tiling_mod.AXIS_ROW
@@ -89,5 +89,5 @@ def bincount_sharded(ids: jax.Array, length: int,
 
     mapped = shard_map(shard_fn, mesh=mesh, in_specs=(t.spec(),),
                        out_specs=tiling_mod.replicated(1).spec(),
-                       check_rep=False)
+                       check_vma=False)
     return mapped(ids).astype(jnp.int32)

@@ -121,7 +121,7 @@ def assign_accumulate(points: jax.Array, centers: jax.Array, k: int,
     """One fused pass over the whole (sharded) point matrix: (k, d)
     cluster sums and (k,) counts. Traceable — the k-means drivers run
     all iterations as one dispatch with this inside ``fori_loop``."""
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     mesh = mesh or mesh_mod.get_mesh()
     n, d = points.shape
@@ -156,7 +156,7 @@ def assign_accumulate(points: jax.Array, centers: jax.Array, k: int,
     mapped = shard_map(
         shard_fn, mesh=mesh,
         in_specs=(t.spec(), rep.spec(), rep.spec()),
-        out_specs=(rep.spec(), rep.spec()), check_rep=False)
+        out_specs=(rep.spec(), rep.spec()), check_vma=False)
     sums, cnt = mapped(points, cpad, cnorm)
     return sums[:k], cnt[0, :k]
 

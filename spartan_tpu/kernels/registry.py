@@ -63,10 +63,7 @@ VMEM_BUDGET = 8 * 1024 * 1024
 def _platform() -> str:
     import jax
 
-    try:
-        return jax.devices()[0].platform
-    except Exception:  # noqa: BLE001 - no backend yet
-        return "cpu"
+    return jax.devices()[0].platform
 
 
 def mode() -> str:
@@ -420,17 +417,8 @@ def node_selection(node: Any) -> Optional[Selection]:
             p = int(mesh.shape.get(axis, 1))
             n = node.x.shape[-1] if node.x.ndim else 0
             m = -(-n // p) if p else n
-            sel = select("sort_exchange", (n,), node.x.dtype, moved,
-                         mesh, p=p, m=m)
-            if sel.pallas and node.x.ndim == 1 \
-                    and not interpret_mode():
-                # 1-D sorts on the real chip ride the payload-only
-                # ragged_all_to_all transport (ops/sort.py) — there is
-                # no padded send buffer to pack
-                return _fallback("sort_exchange",
-                                 "ragged transport carries 1-D TPU "
-                                 "sorts (no padded buffer to pack)")
-            return sel
+            return select("sort_exchange", (n,), node.x.dtype, moved,
+                          mesh, p=p, m=m)
         if name == "StencilExpr":
             return select(
                 "stencil", node.x.shape, node.x.dtype,

@@ -101,7 +101,7 @@ def segment_sum_sharded(vals: jax.Array, ids: jax.Array,
     or ``psum``. Output is replicated either way — the scatter merge
     finishes with an all-gather of the k/p slices, which together cost
     one all-reduce's bytes (the rs+ag decomposition)."""
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     mesh = mesh or mesh_mod.get_mesh()
     axis = tiling_mod.AXIS_ROW
@@ -140,7 +140,7 @@ def segment_sum_sharded(vals: jax.Array, ids: jax.Array,
         shard_fn, mesh=mesh,
         in_specs=(t_vals.spec(), t_ids.spec()),
         out_specs=tiling_mod.replicated(2).spec(),
-        check_rep=False)
+        check_vma=False)
     out = mapped(vals, ids)
     return out[:, 0] if squeeze else out
 

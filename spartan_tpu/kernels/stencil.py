@@ -77,7 +77,7 @@ def halo_stencil(x: jax.Array, w: jax.Array, tiling,
                  sel: registry.Selection, mesh=None) -> jax.Array:
     """SAME-padded stride-1 NHWC conv with the H axis mesh-sharded:
     manual ppermute halo exchange feeding the blocked kernel."""
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     mesh = mesh or mesh_mod.get_mesh()
     axis = tiling.axes[1]
@@ -111,5 +111,5 @@ def halo_stencil(x: jax.Array, w: jax.Array, tiling,
     mapped = shard_map(
         shard_fn, mesh=mesh,
         in_specs=(tiling.spec(), tiling_mod.replicated(4).spec()),
-        out_specs=out_t.spec(), check_rep=False)
+        out_specs=out_t.spec(), check_vma=False)
     return mapped(x, w.astype(jnp.float32))

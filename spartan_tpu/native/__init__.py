@@ -1,13 +1,16 @@
 """ctypes loader for the native C++ runtime components.
 
-Compiles ``spartan_native.cpp`` on first import (g++, cached .so) and
-exposes typed wrappers. Falls back gracefully (``lib() is None``) when no
-toolchain is available; callers keep their pure-Python paths.
+Compiles ``spartan_native.cpp`` on first use (g++) into a .so named by
+the hash of the source, so a copied tree (which keeps no mtimes)
+rebuilds exactly when the source changed, and exposes typed wrappers.
+Falls back gracefully (``lib() is None``) when no toolchain is
+available; callers keep their pure-Python paths.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,20 +20,32 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "spartan_native.cpp")
-_SO = os.path.join(_DIR, "libspartan_native.so")
+
+
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libspartan_native-{digest}.so")
+
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def _build(so: str) -> bool:
+    # build beside the target and rename: concurrent builders (test
+    # workers) never load a half-written library
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           _SRC, "-o", _SO]
+           _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, so)
         return True
     except Exception:
+        if os.path.exists(tmp):
+            os.remove(tmp)
         return False
 
 
@@ -40,12 +55,11 @@ def lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        fresh = (not os.path.exists(_SO)
-                 or os.path.getmtime(_SO) < os.path.getmtime(_SRC))
-        if fresh and not _build():
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
             return None
         try:
-            l = ctypes.CDLL(_SO)
+            l = ctypes.CDLL(so)
         except OSError:
             return None
         i64p = ctypes.POINTER(ctypes.c_int64)

@@ -21,9 +21,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-
-from ..utils.compat import pcast, shard_map
+from jax import lax, shard_map
 
 from ..array.tiling import Tiling
 from ..parallel import collectives as coll
@@ -132,11 +130,11 @@ def ring_attention(q, k, v, causal: bool = False,
         # pcast-to-varying: these carries become device-varying once
         # the ring runs, so the initial values must be marked varying
         # too (pvary was deprecated in favor of pcast)
-        acc = pcast(jnp.zeros(ql.shape, jnp.float32), (mesh_axis,),
+        acc = lax.pcast(jnp.zeros(ql.shape, jnp.float32), (mesh_axis,),
                         to="varying")
-        m = pcast(jnp.full((ql.shape[1], ql.shape[0]), _NEG_INF,
+        m = lax.pcast(jnp.full((ql.shape[1], ql.shape[0]), _NEG_INF,
                                jnp.float32), (mesh_axis,), to="varying")
-        den = pcast(jnp.zeros((ql.shape[1], ql.shape[0]), jnp.float32),
+        den = lax.pcast(jnp.zeros((ql.shape[1], ql.shape[0]), jnp.float32),
                         (mesh_axis,), to="varying")
 
         def body(s, carry):

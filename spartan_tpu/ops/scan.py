@@ -84,7 +84,7 @@ def blocked_scan(x: jax.Array, op: str = "add", mesh=None,
     keeps trailing-axis sharding intact. Traceable; falls back to the
     local cumulative op when the axis does not shard evenly (same
     contract as sample_sort)."""
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     if op not in _LOCAL:
         raise ValueError(f"unknown scan op {op!r}")

@@ -52,8 +52,7 @@ _SAMPLES = 64  # per-shard splitter samples (capped at shard size)
 
 
 def _kernel(xs: jax.Array, axis, p: int, s: int, n: int,
-            with_indices: bool = False, ragged: bool = False,
-            pack_sel=None):
+            with_indices: bool = False, pack_sel=None):
     """One shard's sample sort over its ``m``-slot row of the padded
     array; ``n`` is the true (unpadded) global length, so slots with
     global index >= n form the validity channel. With ``with_indices``
@@ -62,18 +61,12 @@ def _kernel(xs: jax.Array, axis, p: int, s: int, n: int,
     distributed argsort (padding sits at the array's end, so a valid
     element's padded index IS its original index).
 
-    ``ragged`` selects the transport for both exchanges (the routing
-    math — counts, offsets, chunk cuts — is identical either way):
-
-    * padded (default): fixed ``(p, m)`` ``all_to_all`` buffers — O(n)
-      wire bytes per device for O(n/p) payload, but supported on every
-      backend (round-4 verdict Weak #7's p-fold inflation);
-    * ragged: two-phase — per-peer counts ride an ``all_gather``
-      (p x p ints), then ``lax.ragged_all_to_all`` moves ONLY the
-      payload bytes. TPU-only: XLA:CPU has no ragged-all-to-all
-      thunk, so the CPU test mesh exercises the padded transport and
-      the shared routing math (the primitive's offset semantics are
-      validated on the real chip in tests/test_sort.py)."""
+    Both exchanges move fixed ``(p, m)`` ``all_to_all`` buffers: O(n)
+    wire bytes per device for O(n/p) payload. ``lax.ragged_all_to_all``
+    would move only the payload, but XLA:TPU lays a 1-D operand out as
+    one 128-lane row per element: at 16M elements over four v5e chips
+    its two temporaries took 8 GB each and the program did not fit
+    (PR 21's compile against a described v5e:2x2)."""
     m = xs.shape[0]
     dt = xs.dtype
     me = jax.lax.axis_index(axis)
@@ -98,35 +91,15 @@ def _kernel(xs: jax.Array, axis, p: int, s: int, n: int,
         return jax.lax.all_to_all(mat, axis, split_axis=0,
                                   concat_axis=0, tiled=True)
 
-    def ragged_exchange(vals, out_size, in_off, sizes, out_off, rsizes):
-        return jax.lax.ragged_all_to_all(
-            vals, jnp.zeros((out_size,), vals.dtype),
-            in_off.astype(jnp.int32), sizes.astype(jnp.int32),
-            out_off.astype(jnp.int32), rsizes.astype(jnp.int32),
-            axis_name=axis)
-
     # -- bucket exchange -------------------------------------------------
     # valid elements are the sorted prefix, so per-destination runs are
-    # contiguous: counts/starts drive both transports
+    # contiguous: counts/starts drive the send buffer
     dst = jnp.searchsorted(splitters, xs_sorted,
                            side="right").astype(jnp.int32)
     dst = jnp.where(inv_s == 1, p, dst)     # padding: routed nowhere
     counts = jnp.bincount(dst, length=p + 1)[:p]
     starts = (jnp.cumsum(counts) - counts).astype(jnp.int32)
-    if ragged:
-        C = jax.lax.all_gather(counts, axis)        # C[i, j]: i -> j
-        rsizes = C[:, me]
-        out_off = (jnp.cumsum(C, axis=0) - C)[me]   # pack by sender
-        k = jnp.sum(rsizes)
-        vals = ragged_exchange(xs_sorted, p * m, starts, counts,
-                               out_off, rsizes)
-        valid_key = (jnp.arange(p * m) >= k).astype(jnp.int32)
-        if with_indices:
-            ridx = ragged_exchange(src_idx, p * m, starts, counts,
-                                   out_off, rsizes)
-        else:
-            ridx = None
-    elif pack_sel is not None:
+    if pack_sel is not None:
         # kernel-layer pack (spartan_tpu/kernels/exchange.py): bucket
         # runs are contiguous in the sorted stream, so the send buffer
         # is a batch of dynamic slices — the Pallas kernel replaces
@@ -180,15 +153,6 @@ def _kernel(xs: jax.Array, axis, p: int, s: int, n: int,
     hi = jnp.minimum(off + k, out_starts + m)
     cnt = jnp.maximum(hi - lo, 0).astype(jnp.int32)    # (p,) chunk sizes
     st = jnp.clip((lo - out_starts), 0, m).astype(jnp.int32)
-    if ragged:
-        in_off = jnp.clip(lo - off, 0, p * m - 1).astype(jnp.int32)
-        C2 = jax.lax.all_gather(cnt, axis)             # C2[i, j]: i -> j
-        rsz = C2[:, me]
-        out_vals = ragged_exchange(bucket, m, in_off, cnt, st, rsz)
-        if not with_indices:
-            return out_vals
-        out_idx = ragged_exchange(bidx, m, in_off, cnt, st, rsz)
-        return out_vals, out_idx
     gather_idx = jnp.clip(lo[:, None] - off + jnp.arange(m)[None, :],
                           0, p * m - 1).astype(jnp.int32)
     rchunks = exchange(bucket[gather_idx])             # (p, m)
@@ -261,7 +225,7 @@ def _run(x: jax.Array, mesh, with_indices: bool,
     vmapped) kernel, unpad. N-d inputs keep their BATCH-axis shardings
     (minus any use of the collective axis) — a batch-sharded array is
     never replicated to sort it."""
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     mesh = mesh or mesh_mod.get_mesh()
     n = int(x.shape[-1])
@@ -275,27 +239,18 @@ def _run(x: jax.Array, mesh, with_indices: bool,
     t = tiling_mod.Tiling(batch + (name,))
     xp = redist_mod.constrain(xp, t, mesh)
     s = min(_SAMPLES, m)
-    # payload-only exchanges where the backend has the ragged thunk;
-    # the vmapped (batched) path keeps the padded transport (no
-    # batching rule for ragged_all_to_all)
-    ragged = (x.ndim == 1
-              and next(iter(mesh.devices.flat)).platform == "tpu")
-    pack_sel = None
-    if not ragged:
-        # padded transport: the kernel layer may pack the send buffer
-        # with the Pallas dynamic-slice kernel instead of XLA scatter
-        # (batched sorts vmap it — pallas_call carries the batch as an
-        # extra grid dim). 1-D TPU sorts never get here: the ragged
-        # transport already moves payload-only bytes.
-        from ..kernels import registry as kernels_mod
+    # the kernel layer may pack the send buffer with the Pallas
+    # dynamic-slice kernel instead of XLA scatter (batched sorts vmap
+    # it — pallas_call carries the batch as an extra grid dim)
+    from ..kernels import registry as kernels_mod
 
-        sel = kernels_mod.select("sort_exchange", (n,), x.dtype, t,
-                                 mesh, p=p, m=m)
-        pack_sel = sel if sel.pallas else None
+    sel = kernels_mod.select("sort_exchange", (n,), x.dtype, t, mesh,
+                             p=p, m=m)
+    pack_sel = sel if sel.pallas else None
 
     def row_fn(r):
         out = _kernel(r, name, p, s, n, with_indices=with_indices,
-                      ragged=ragged, pack_sel=pack_sel)
+                      pack_sel=pack_sel)
         return out[1] if with_indices else out
 
     def block_fn(v):  # local block: batch axes (locally) whole
@@ -307,7 +262,7 @@ def _run(x: jax.Array, mesh, with_indices: bool,
     # the replication checker has no rule for pallas_call; only the
     # kernel-packed variant relaxes it, so the GSPMD lowering stays
     # byte-identical with the kernel layer off
-    kw = {"check_rep": False} if pack_sel is not None else {}
+    kw = {"check_vma": False} if pack_sel is not None else {}
     mapped = shard_map(block_fn, mesh=mesh,
                        in_specs=(t.spec(),), out_specs=t.spec(), **kw)
     out = mapped(xp)
@@ -365,7 +320,7 @@ def distributed_topk(x: jax.Array, k: int, largest: bool = True,
     ragged lengths ride the same sentinel masking as the sample sort.
     Smallest-k runs largest-k on the ORDER-FLIPPED key (sentinel
     masked), so int dtypes need no negation."""
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     mesh = mesh or mesh_mod.get_mesh()
     axis = tiling_mod.AXIS_ROW
@@ -430,7 +385,7 @@ def distributed_topk(x: jax.Array, k: int, largest: bool = True,
         _, win = jax.lax.top_k(gk, k)
         return gv[win][None], gi[win][None].astype(jnp.int32)
 
-    kw = {"check_rep": False} if topk_sel.pallas else {}
+    kw = {"check_vma": False} if topk_sel.pallas else {}
     mapped = shard_map(
         kern, mesh=mesh, in_specs=(row.spec(),),
         out_specs=(tiling_mod.Tiling((axis, None)).spec(),) * 2, **kw)

@@ -83,7 +83,7 @@ def ulysses_swap(arr: jax.Array, seq_axis: int, head_axis: int,
                  mesh_axis: str = mesh_mod.AXIS_ROW) -> jax.Array:
     """Ulysses-style axis swap: move the mesh shard from ``seq_axis`` to
     ``head_axis`` with one all-to-all (SURVEY.md §2.6 SP row)."""
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     mesh = mesh_mod.get_mesh()
     ndim = arr.ndim

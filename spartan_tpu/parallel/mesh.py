@@ -208,8 +208,12 @@ def rebuild_mesh(exclude_devices: Sequence = (),
 
 def reset_epoch_for_tests() -> None:
     """Restore the full-device, epoch-0 world (test isolation only:
-    production epochs are monotonic by design)."""
+    production epochs are monotonic by design). Plans are keyed by
+    epoch, so the cached ones go too: a later test's epoch 1 may hold
+    other survivors than this one's."""
     global _EPOCH, _global_mesh, _excluded_ids
+    from ..expr import base as expr_base
+
     with _epoch_lock:
         _EPOCH = 0
         _global_mesh = None
@@ -217,6 +221,7 @@ def reset_epoch_for_tests() -> None:
         _shape_history.clear()
         _state.mesh = None
         _state.epoch = 0
+    expr_base.clear_compile_cache()
 
 
 def mesh_axis_sizes(mesh: Optional[Mesh] = None) -> Tuple[int, int]:

@@ -54,7 +54,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
     ticks = n_micro + n_stages - 1
     fwd = [(i, (i + 1) % n_stages) for i in range(n_stages)]
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     params_spec = jax.tree.map(lambda _: P(axis), stage_params)
 

@@ -647,7 +647,7 @@ def apply_schedule(val: Any, schedule: Schedule, src: Tiling,
     the value to ``src`` (the layout the plan priced from), then run
     the collective steps over local blocks. Callers must have checked
     divisibility (``decide`` does)."""
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     val = jax.lax.with_sharding_constraint(val, src.sharding(mesh))
     sizes = dict(mesh.shape)
@@ -669,10 +669,10 @@ def apply_schedule(val: Any, schedule: Schedule, src: Tiling,
                                              axis=step.axis)
         return x
 
-    # check_rep off: the slice step's axis_index makes replication
+    # check_vma off: the slice step's axis_index makes replication
     # tracking version-dependent; out_specs already pins the contract
     mapped = shard_map(kern, mesh=mesh, in_specs=(src.spec(),),
-                       out_specs=dst.spec(), check_rep=False)
+                       out_specs=dst.spec(), check_vma=False)
     return mapped(val)
 
 

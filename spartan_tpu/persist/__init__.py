@@ -1,8 +1,7 @@
 """Warm-start persistence: the crash-safe plan & executable store.
 
 A serving replica restart used to recompile the world — fatal for
-rolling restarts of a fleet, and exactly the failure mode behind the
-BENCH_r05 cold-start timeouts (docs/BENCH.md "r04 -> r05 verdict").
+rolling restarts of a fleet.
 This package makes the plan cache and the compiled executables
 DURABLE: ``evaluate()``'s miss path consults the store before the
 optimizer runs, pre-seeds the compile cache with the deserialized AOT

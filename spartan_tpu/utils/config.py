@@ -162,8 +162,8 @@ class FlagRegistry:
     def snapshot_nondefault(self) -> Dict[str, Any]:
         """Flags whose value differs from the compiled-in default —
         the compact attribution record every committed benchmark
-        carries (a BENCH_r05 TPU regression must be attributable to
-        flag state vs compile-cache growth without rerunning)."""
+        carries (a regression must be attributable to flag state vs
+        compile-cache growth without rerunning)."""
         return {f.name: f.value for f in self._flags.values()
                 if f.value != f.default}
 
@@ -345,11 +345,6 @@ FLAGS.define_str(
     "in TensorBoard/Perfetto). st.profile's XPlane tier and the "
     "profile_sample_every sampler capture into throwaway temp dirs — "
     "they parse and delete, never writing here.")
-FLAGS.define_str(
-    "compilation_cache_dir", "",
-    "Enable JAX's persistent compilation cache at this path (empty = "
-    "off): compiled XLA programs survive process restarts, amortizing "
-    "long compiles like the Pallas-in-loop sparse iteration.")
 FLAGS.define_int("default_mesh_1d", 0,
                  "If >0, force the default mesh to this many devices.")
 FLAGS.define_str("placement", "auto",

@@ -62,7 +62,11 @@ def test_status():
 
 
 def test_initialize():
+    import jax
+
+    prev = jax.config.jax_compilation_cache_dir
     leftover = st.initialize(["--log_level=1", "extra"])
+    jax.config.update("jax_compilation_cache_dir", prev)
     assert leftover == ["extra"]
     assert st.FLAGS.log_level == 1
     st.FLAGS.reset_all()

@@ -76,7 +76,7 @@ def test_ulysses_causal(mesh1d):
 
 
 def test_collectives_in_shard_map(mesh1d):
-    from spartan_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     mesh = mesh_mod.get_mesh()
     x = np.arange(8, dtype=np.float32)

@@ -215,24 +215,30 @@ def test_evaluate_with_recovery_api(monkeypatch):
     np.testing.assert_allclose(np.asarray(out.glom()), 48.0)
 
 
-def test_persistent_compilation_cache_flag(tmp_path):
-    """--compilation_cache_dir wires JAX's persistent cache: after an
-    initialize() + compile, the cache directory holds entries."""
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compilation_cache_dir_resolution(monkeypatch, tmp_path, from_env):
+    """st.initialize() places JAX's persistent compilation cache: a set
+    JAX_COMPILATION_CACHE_DIR is left to JAX, else <checkout>/.jax_cache
+    (a fixed path: the cache key includes it)."""
+    import os
+
     import jax
 
     import spartan_tpu as st
-    from spartan_tpu.utils.config import FLAGS
 
-    cache = str(tmp_path / "xla_cache")
+    prev = jax.config.jax_compilation_cache_dir
+    placed = str(tmp_path / "placed")
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+        jax.config.update("jax_compilation_cache_dir", placed)  # as JAX
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     try:
-        st.initialize(["--compilation_cache_dir", cache])
-        import numpy as np
-
-        x = st.from_numpy(np.arange(4096, dtype=np.float32))
-        # a compile long enough to clear the 1s persistence floor is
-        # not guaranteed on CPU; assert the config took instead
-        assert jax.config.jax_compilation_cache_dir == cache
-        float((x * 2.0).sum().glom())
+        st.initialize([])
+        checkout = os.path.dirname(os.path.dirname(st.__file__))
+        want = placed if from_env else os.path.join(checkout, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == want
     finally:
-        FLAGS.reset_all()
-        jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+

@@ -269,23 +269,39 @@ def test_dot_column_delta_bitequal():
         r.glom(), _full_reference(build, r_np, a2_np))
 
 
-def test_matmul_row_delta_bitequal():
+@pytest.mark.parametrize("split_k", [False, True])
+def test_matmul_row_delta_bitequal(split_k):
+    """Dirty rows of A recompute only those output rows — unless the
+    contracted dim is split over the mesh: the full plan then sums
+    per-shard partial products, which a restricted dot would round
+    differently, so the engine recomputes in full. Bit-equal both ways."""
+    from spartan_tpu.array import tiling
+
     a_np = _rand((64, 32), seed=12)
     b_np = _rand((32, 48), seed=13)
-    a, b = _arr(a_np), _arr(b_np)
+    ta = tiling.Tiling(("x", "y")) if split_k else tiling.row(2)
+    tb = tiling.replicated(2)
+
+    def arrs(x, y):
+        return (da_mod.from_numpy(np.ascontiguousarray(x), tiling=ta),
+                da_mod.from_numpy(np.ascontiguousarray(y), tiling=tb))
 
     def build(x, y):
         return lazify(x) @ lazify(y)
 
+    a, b = arrs(a_np, b_np)
     evaluate(build(a, b))
     a2 = a.update((slice(30, 32), slice(0, 32)), 0.25)
     a2_np = a_np.copy()
     a2_np[30:32] = 0.25
     h0 = _counter("incremental_hits")
+    f0 = _counter("incremental_fallbacks")
     r = evaluate(build(a2, b))
-    assert _counter("incremental_hits") == h0 + 1
-    assert np.array_equal(
-        r.glom(), _full_reference(build, a2_np, b_np))
+    assert _counter("incremental_hits") == h0 + (0 if split_k else 1)
+    assert _counter("incremental_fallbacks") == f0 + (1 if split_k else 0)
+    FLAGS.incremental = False
+    ref = evaluate(build(*arrs(a2_np, b_np))).glom()
+    assert np.array_equal(r.glom(), ref)
 
 
 def test_loop_carry_falls_back_full_and_stays_correct():

@@ -1,6 +1,6 @@
 """tools/lint_repo.py in the tier-1 flow: the codebase must stay clean
 under its own AST lint, and the lint itself must catch the bug classes
-it exists for (direct shard_map imports; Expr subclasses missing the
+it exists for (the pre-0.9 experimental shard_map; Expr subclasses missing the
 structural hooks; raw wall-clock timing that escapes the trace)."""
 
 import ast
@@ -18,7 +18,7 @@ def test_repo_is_clean():
     assert findings == [], "\n".join(str(f) for f in findings)
 
 
-def test_catches_direct_shard_map_import(tmp_path):
+def test_catches_experimental_shard_map(tmp_path):
     bad = tmp_path / "bad_mod.py"
     bad.write_text(
         "from jax.experimental.shard_map import shard_map\n"
@@ -26,12 +26,13 @@ def test_catches_direct_shard_map_import(tmp_path):
         "f = jax.experimental.shard_map\n")
     tree = ast.parse(bad.read_text(), filename=str(bad))
     findings = lint_repo.lint_shard_map_imports(str(bad), tree)
-    assert any(f.rule == "shard-map-shim" for f in findings)
+    assert any(f.rule == "experimental-shard-map" for f in findings)
 
 
-def test_allows_compat_shim_import(tmp_path):
+def test_allows_jax_shard_map(tmp_path):
     ok = tmp_path / "ok_mod.py"
-    ok.write_text("from ..utils.compat import shard_map\n")
+    ok.write_text("from jax import shard_map\nimport jax\n"
+                  "f = jax.shard_map\n")
     tree = ast.parse(ok.read_text(), filename=str(ok))
     assert lint_repo.lint_shard_map_imports(str(ok), tree) == []
 

@@ -2,11 +2,10 @@
 
 Eighteen repo-specific rules that generic linters cannot know:
 
-1. ``shard_map`` must be imported ONLY through the version-compat shim
-   ``spartan_tpu/utils/compat.py`` (PR 1): importing it from jax
-   directly (``jax.shard_map`` / ``jax.experimental.shard_map``) at a
-   call site reintroduces the cross-version breakage the shim exists
-   to absorb.
+1. ``shard_map`` comes from ``jax`` itself (``from jax import
+   shard_map``): ``jax.experimental.shard_map`` is the pre-0.9
+   location, with the old ``check_rep`` spelling, and the installed
+   JAX is the only one the code supports.
 
 2. Every concrete ``Expr`` subclass must provide ``_sig`` and
    ``replace_children`` somewhere below the ``Expr`` base — a subclass
@@ -207,9 +206,6 @@ from typing import Dict, List, Optional, Set, Tuple
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PACKAGE = os.path.join(REPO, "spartan_tpu")
 
-# the one module allowed to touch jax's shard_map export directly
-SHARD_MAP_SHIM = os.path.join("spartan_tpu", "utils", "compat.py")
-
 # abstract Expr layers that intentionally leave the hooks to subclasses
 _ABSTRACT_EXPRS = {"Expr"}
 
@@ -407,51 +403,24 @@ def _iter_py_files(root: str = PACKAGE) -> List[str]:
     return sorted(out)
 
 
-def _is_shim(path: str) -> bool:
-    return os.path.relpath(path, REPO) == SHARD_MAP_SHIM
-
-
 def lint_shard_map_imports(path: str, tree: ast.AST) -> List[Finding]:
-    """Rule 1: no direct jax shard_map import outside the shim."""
-    if _is_shim(path):
-        return []
+    """Rule 1: no ``jax.experimental.shard_map`` (import or attribute)."""
     findings: List[Finding] = []
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom):
-            mod = node.module or ""
-            binds = any(a.name == "shard_map" or a.asname == "shard_map"
-                        for a in node.names)
-            from_shim = mod.endswith("utils.compat") or mod == "compat"
-            if "shard_map" in mod and not from_shim:
-                findings.append(Finding(
-                    path, node.lineno, "shard-map-shim",
-                    f"import from {mod!r}: import shard_map from "
-                    "spartan_tpu.utils.compat (the version shim), "
-                    "not from jax directly"))
-            elif binds and not from_shim:
-                findings.append(Finding(
-                    path, node.lineno, "shard-map-shim",
-                    f"binds shard_map from {mod!r}: only "
-                    "spartan_tpu.utils.compat may import it from jax"))
+            names = [node.module or ""]
         elif isinstance(node, ast.Import):
-            for a in node.names:
-                if "shard_map" in a.name:
-                    findings.append(Finding(
-                        path, node.lineno, "shard-map-shim",
-                        f"import {a.name}: use the "
-                        "spartan_tpu.utils.compat shim"))
+            names = [a.name for a in node.names]
         elif isinstance(node, ast.Attribute) and node.attr == "shard_map":
-            # jax.experimental.shard_map / jax.shard_map attribute use
-            root = node.value
-            parts = []
-            while isinstance(root, ast.Attribute):
-                parts.append(root.attr)
-                root = root.value
-            if isinstance(root, ast.Name) and root.id == "jax":
+            names = [ast.unparse(node)]
+        else:
+            continue
+        for name in names:
+            if "experimental.shard_map" in name:
                 findings.append(Finding(
-                    path, node.lineno, "shard-map-shim",
-                    "attribute access on jax's shard_map: use the "
-                    "spartan_tpu.utils.compat shim"))
+                    path, node.lineno, "experimental-shard-map",
+                    f"{name}: use jax.shard_map (from jax import "
+                    "shard_map)"))
     return findings
 
 
