@@ -14,6 +14,7 @@ hard part 5).
 
 from __future__ import annotations
 
+import contextlib
 import os
 from typing import Any, Callable, List, Optional, Sequence, Tuple, Union
 
@@ -22,7 +23,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
+from ..obs import trace as trace_mod
 from ..parallel import mesh as mesh_mod
+from ..utils import profiling as prof
 from . import extent as extent_mod
 from . import tiling as tiling_mod
 from .extent import TileExtent
@@ -331,11 +334,7 @@ class DistArray:
 
     def glom(self) -> np.ndarray:
         """Fetch the whole array to the host (the reference's ``glom``)."""
-        from ..utils import profiling as prof
-
-        with prof.phase("fetch") as sp:
-            sp.set(shape=self.shape, dtype=str(self.dtype))
-            return np.asarray(jax.device_get(self.jax_array))
+        return fetch_to_host(self.jax_array)[0]
 
     def fetch(self, region: Union[TileExtent, tuple, slice, int]
               ) -> np.ndarray:
@@ -540,6 +539,25 @@ class DistArray:
         return DistArray(out, self.tiling, self.mesh)
 
 
+# -- host transfers -----------------------------------------------------
+
+
+def fetch_to_host(x: jax.Array) -> Tuple[np.ndarray, float]:
+    """``x`` as a host array, and the wall seconds of its ``fetch``
+    phase. The device->host copy is enqueued first, right behind the
+    computation; the wait for the computation is the ``fetch_wait``
+    span inside ``fetch``, and what follows it is the rest of the
+    transfer and the conversion."""
+    ctx = prof.phase("fetch")
+    with ctx as sp:
+        sp.set(shape=tuple(x.shape), dtype=str(x.dtype))
+        x.copy_to_host_async()
+        with prof.span("fetch_wait"):
+            x.block_until_ready()
+        out = np.asarray(jax.device_get(x))
+    return out, ctx.seconds
+
+
 # -- creation -----------------------------------------------------------
 
 
@@ -559,8 +577,17 @@ def from_numpy(arr: Any, tiling: Optional[Tiling] = None,
     arr = np.asarray(arr)
     mesh = mesh or mesh_mod.get_mesh()
     t = _resolve_tiling(arr.shape, tiling, tile_hint, mesh)
-    jarr = jax.device_put(arr, t.sharding(mesh))
+    with upload_span(arr):
+        jarr = jax.device_put(arr, t.sharding(mesh))
     return DistArray(jarr, t, mesh)
+
+
+def upload_span(arr: np.ndarray):
+    """The ``upload`` span around the host->device copy of ``arr``
+    (nothing at all when tracing is off)."""
+    if not trace_mod._TRACE_FLAG._value:
+        return contextlib.nullcontext()
+    return trace_mod.span("upload", bytes=int(arr.nbytes))
 
 
 def from_jax(arr: jax.Array, tiling: Optional[Tiling] = None,

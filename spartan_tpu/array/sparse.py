@@ -24,8 +24,9 @@ import numpy as np
 
 from ..ops.segment import segment_sum
 from ..parallel import mesh as mesh_mod
+from ..utils import profiling as prof
 from . import tiling as tiling_mod
-from .distarray import DistArray
+from .distarray import DistArray, fetch_to_host, upload_span
 from .tiling import Tiling
 
 
@@ -383,13 +384,17 @@ class SparseDistArray:
             return self._plan
         from ..ops.segment import SegmentPlan
 
-        rows = np.asarray(jax.device_get(self.rows))
-        data = np.asarray(jax.device_get(self.data))
-        cols = np.asarray(jax.device_get(self.cols))
-        plan = SegmentPlan(rows, self.shape[0])
-        self._pdata = jnp.asarray(plan.reorder(data))
-        self._pcols = jnp.asarray(plan.reorder(cols, fill=0)
-                                  .astype(np.int32))
+        with prof.span("segment_plan", entries=self.nse):
+            rows = fetch_to_host(self.rows)[0]
+            data = fetch_to_host(self.data)[0]
+            cols = fetch_to_host(self.cols)[0]
+            plan = SegmentPlan(rows, self.shape[0])
+            pdata = plan.reorder(data)
+            pcols = plan.reorder(cols, fill=0).astype(np.int32)
+            with upload_span(pdata):
+                self._pdata = jnp.asarray(pdata)
+            with upload_span(pcols):
+                self._pcols = jnp.asarray(pcols)
         self._plan = plan
         return plan
 
@@ -482,11 +487,12 @@ class SparseDistArray:
         this object's lifetime — call :meth:`clear_cache` to release it.
         SparseDistArray is immutable, so the cache cannot go stale."""
         if self._transition is None:
-            out_deg = np.asarray(jax.device_get(self.rsums()))
-            inv = np.where(out_deg > 0,
-                           1.0 / np.maximum(out_deg, 1e-30), 0.0)
-            self._transition = self.scale_rows(
-                inv.astype(np.float32)).transpose()
+            with prof.span("transition", entries=self.nse):
+                out_deg = fetch_to_host(self.rsums())[0]
+                inv = np.where(out_deg > 0,
+                               1.0 / np.maximum(out_deg, 1e-30), 0.0)
+                self._transition = self.scale_rows(
+                    inv.astype(np.float32)).transpose()
         return self._transition
 
     def clear_cache(self) -> None:

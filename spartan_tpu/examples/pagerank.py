@@ -16,7 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..array.distarray import fetch_to_host
 from ..array.sparse import SparseDistArray
+from ..utils import profiling as prof
 
 
 def _teleport_body(y, damping, n):
@@ -56,8 +58,9 @@ def pagerank(links: SparseDistArray, damping: float = 0.85,
     rank = jnp.full((n,), 1.0 / n, jnp.float32)
     damp = jnp.float32(damping)
     if tol == 0 and T._default_windowed():
-        return np.asarray(jax.device_get(
-            _pagerank_fused(T, rank, damp, num_iter)))
+        with prof.phase("dispatch"):
+            out = _pagerank_fused(T, rank, damp, num_iter)
+        return fetch_to_host(out)[0]
     for _ in range(num_iter):
         new = _teleport(T.spmv(rank), damp, n=n)
         if tol > 0:
@@ -68,7 +71,7 @@ def pagerank(links: SparseDistArray, damping: float = 0.85,
                 break
         else:
             rank = new
-    return np.asarray(jax.device_get(rank))
+    return fetch_to_host(rank)[0]
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -90,6 +90,7 @@ def partition_pack(xs: jax.Array, starts: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((p * mr, LANE), jnp.int32),
         interpret=sel.interpret,
+        name="partition_pack",
     )(starts.astype(jnp.int32), counts.astype(jnp.int32), xs2)
     out = jax.lax.bitcast_convert_type(out.reshape(p, mr * LANE), dt)
     return out[:, :m]

@@ -56,6 +56,7 @@ def bincount_block(ids: jax.Array, length: int,
         out_specs=pl.BlockSpec((1, k_total), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, k_total), jnp.float32),
         interpret=interpret,
+        name="bincount_block",
     )(ids2d)
     return out[0, :length]
 

@@ -113,6 +113,7 @@ def _block_kernel(points: jax.Array, cpad: jax.Array, cnorm: jax.Array,
             pltpu.VMEM((1, kpad), jnp.float32),
         ],
         interpret=interpret,
+        name="kmeans_lloyd",
     )(points, cpad, cnorm, lim2)
 
 

@@ -86,6 +86,7 @@ def segment_sum_block(vals: jax.Array, ids: jax.Array,
         out_shape=jax.ShapeDtypeStruct((k_total, vals.shape[1]),
                                        vals.dtype),
         interpret=interpret,
+        name="segment_sum_block",
     )(ids2d, vals)
     out = out[:k, :d]
     return out[:, 0] if squeeze else out
@@ -216,5 +217,6 @@ def windowed_segsum(vals: jax.Array, ids2d: jax.Array, wb: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((rows_pad, 128), jnp.float32),
         interpret=registry.interpret_mode(),
+        name="windowed_segsum",
     )
     return f(wb, ids2d, vals2d)

@@ -69,6 +69,7 @@ def conv_block(x: jax.Array, w: jax.Array, hb: int,
         out_specs=pl.BlockSpec((1, hb, wo, o), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, nh * hb, wo, o), jnp.float32),
         interpret=interpret,
+        name="conv_block",
     )(x, w)
     return out[:, :ho]
 

@@ -111,6 +111,7 @@ def shard_topk(key: jax.Array, k: int, sentinel,
             pltpu.VMEM((1, kpad), jnp.int32),
         ],
         interpret=sel.interpret,
+        name="shard_topk",
     )(key2, idx2)
     # clamp the index payload so downstream gathers stay in bounds even
     # for sentinel candidates (they never win a slot)

@@ -1,15 +1,24 @@
 """Span tracer: nested wall-time spans for the plan lifecycle.
 
 Every ``evaluate()`` emits a span tree (build -> sign -> optimize ->
-per-pass -> tiling -> compile -> dispatch -> fetch; see
+per-pass -> tiling -> compile -> dispatch -> fetch, with the wait for
+the device inside fetch as ``fetch_wait``; see
 ``utils/profiling.phase``) carrying the plan-cache key, hit/miss
-status and the user build site. Spans are ring-buffered in memory
-(``FLAGS.trace_ring``) and exportable as Chrome trace-event JSON via
-``st.trace_export(path)`` — load the file at https://ui.perfetto.dev
-or chrome://tracing. ``FLAGS.trace`` toggles recording; the recording
-cost is one clock pair + a lock-guarded deque append per span
-(benchmarks/obs_overhead.py gates it at <=5% of a steady-state
-evaluate).
+status and the user build site. Host time outside that tree has spans
+too: host->device copies (``upload``), a served request's submit,
+queue, linger and wake (``serve_*``, carrying its flight-recorder
+``rid`` and dispatch id), and Python's garbage collections (``gc``).
+Spans are ring-buffered in memory (``FLAGS.trace_ring``) and
+exportable as Chrome trace-event JSON via ``st.trace_export(path)`` —
+load the file at https://ui.perfetto.dev or chrome://tracing.
+``FLAGS.trace`` toggles recording; the recording cost is one clock
+pair + a lock-guarded deque append per span; off, nothing is appended
+and no gc callback runs. What tracing costs end to end on the chip
+(the same benchmark runs with ``FLAGS.trace`` on and off, no profiler
+capture) is in PERF.md.
+
+:func:`record` appends a span whose edges were stamped earlier (the
+serve engine's request stamps), so building it reads no clock.
 
 Device-side attribution is separate: ``Expr.lower`` wraps every node's
 kernel body in ``jax.named_scope`` (``FLAGS.trace_annotations``) so
@@ -24,6 +33,8 @@ layers — so every subsystem can emit spans without import cycles.
 from __future__ import annotations
 
 import contextlib
+import gc
+import itertools
 import json
 import os
 import threading
@@ -39,10 +50,11 @@ from ..utils.config import FLAGS
 _TRACE_FLAG = FLAGS.define_bool(
     "trace", True,
     "Record host-side spans (evaluate/sign/optimize/per-pass/tiling/"
-    "compile/dispatch/fetch) into the in-memory ring buffer for "
-    "st.trace_export. Cheap (a clock pair + deque append per span; "
-    "<=5% of a steady-state evaluate, benchmarks/obs_overhead.py); "
-    "turn off to make the observability layer zero-cost.")
+    "compile/dispatch/fetch, uploads, serve requests, garbage "
+    "collections) into the in-memory ring buffer for st.trace_export. "
+    "A clock pair + deque append per span when on (its end-to-end cost "
+    "on the chip, on against off with no profiler capture: PERF.md); "
+    "off, nothing is recorded and no gc callback runs.")
 _RING_FLAG = FLAGS.define_int(
     "trace_ring", 4096,
     "Maximum spans retained in the in-memory trace ring buffer; older "
@@ -149,6 +161,7 @@ _lock = threading.Lock()
 _ring: Deque[Span] = deque(maxlen=max(1, FLAGS.trace_ring))
 _tls = threading.local()
 _tids: Dict[int, int] = {}  # threading ident -> small stable tid
+_tid_seq = itertools.count()
 # tid -> stack of OPEN spans (entered, not yet exited). The numerics
 # watchdog (obs/numerics.py) reads this from its timer thread to dump
 # the in-flight span tree of a hung dispatch — the ring only ever sees
@@ -157,11 +170,13 @@ _open: Dict[int, List[Span]] = {}
 
 
 def _tid() -> int:
+    """A small stable id for the calling thread. Lock-free (only this
+    thread inserts its own ident), so the gc callback may call it while
+    another frame of the same thread holds ``_lock``."""
     ident = threading.get_ident()
     tid = _tids.get(ident)
     if tid is None:
-        with _lock:
-            tid = _tids.setdefault(ident, len(_tids))
+        tid = _tids.setdefault(ident, next(_tid_seq))
     return tid
 
 
@@ -173,6 +188,8 @@ def _depth(delta: int) -> int:
 
 def _append(sp: Span) -> None:
     global _ring
+    if not _gc_hooked:
+        _hook_gc()
     with _lock:
         size = max(1, _RING_FLAG._value)
         if _ring.maxlen != size:
@@ -281,6 +298,77 @@ def instant(name: str, error: bool = False, **args: Any) -> None:
     _append(sp)
 
 
+def _finished(name: str, t0: float, t1: float,
+              args: Optional[Dict[str, Any]]) -> Span:
+    sp = Span(name, (t0 - _EPOCH) * 1e6, _tid(), 0)
+    sp.dur = (t1 - t0) * 1e6
+    sp.seconds = t1 - t0
+    sp.args = args or None
+    return sp
+
+
+def record(name: str, t0: float, t1: float, **args: Any) -> None:
+    """Append a finished span with explicit edges ``t0``..``t1`` on the
+    tracer clock (:func:`now`), stamped earlier by the caller: the
+    serve engine builds its request spans from the stamps it already
+    takes. One flag read when tracing is off."""
+    if not _TRACE_FLAG._value:
+        return
+    _append(_finished(name, t0, t1, args))
+
+
+# -- garbage collections ---------------------------------------------------
+#
+# One "gc" span per collection, from gc.callbacks. The callback is
+# registered by the first span recorded with tracing on and takes
+# itself out at the first collection it sees with tracing off, so an
+# untraced process runs no callback per collection. It never takes
+# _lock: a collection can start inside any allocation, also while this
+# thread holds _lock (in _append or SpanCtx.__enter__), and the lock is
+# not re-entrant. deque.append is atomic under the GIL.
+
+_gc_lock = threading.Lock()
+_gc_hooked = False
+_gc_t0 = 0.0  # collections never overlap: one start stamp suffices
+
+
+def _hook_gc() -> None:
+    global _gc_hooked
+    with _gc_lock:
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+        _gc_hooked = True
+
+
+def _unhook_gc(phase: str, info: Dict[str, int]) -> None:
+    global _gc_hooked
+    cbs = gc.callbacks
+    if _on_gc not in cbs:
+        return
+    i = cbs.index(_on_gc)
+    del cbs[i]
+    _gc_hooked = False
+    # CPython walks gc.callbacks by index over the live list: the
+    # callback that moved into this slot would miss this phase
+    if i < len(cbs):
+        cbs[i](phase, info)
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    global _gc_t0
+    if not _TRACE_FLAG._value:
+        _unhook_gc(phase, info)
+        return
+    if phase == "start":
+        _gc_t0 = now()
+        return
+    t0, _gc_t0 = _gc_t0, 0.0
+    if t0:  # 0: hooked while this collection ran
+        _ring.append(_finished("gc", t0, now(),
+                               {"generation": info["generation"],
+                                "collected": info["collected"]}))
+
+
 def clear() -> None:
     with _lock:
         _ring.clear()
@@ -349,14 +437,10 @@ def loop_steps_begin(label: str) -> None:
 def record_loop_step(label: str, step: Any) -> None:
     """Host callback target: close a span covering [previous mark, now]
     for iteration ``step`` of the loop ``label``."""
-    if not FLAGS.trace:
+    if not _TRACE_FLAG._value:
         return
     t1 = now()
     with _lock:
         t0 = _loop_prev.get(label, t1)
         _loop_prev[label] = t1
-    sp = Span("loop_step", (t0 - _EPOCH) * 1e6, _tid(), 0)
-    sp.dur = (t1 - t0) * 1e6
-    sp.seconds = t1 - t0
-    sp.set(loop=label, step=int(step))
-    _append(sp)
+    record("loop_step", t0, t1, loop=label, step=int(step))

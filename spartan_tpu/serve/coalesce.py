@@ -145,13 +145,15 @@ def _make_batched(traced: Callable, B: int, nargs: int, mode: str,
     return unrolled
 
 
-def dispatch_batch(plan: Any, requests: List[Any], mesh) -> List[Any]:
+def dispatch_batch(plan: Any, requests: List[Any], mesh,
+                   span: int = 0) -> List[Any]:
     """One coalesced dispatch for ``requests`` (all sharing
     ``plan``'s signature): gather each request's leaves, run the
     batched executable, wrap each request's outputs and seed its
-    expr's result cache. Raises on failure — the engine falls back to
-    solo dispatches (where the resilience policy engine handles
-    classification, per-tenant budgets and retries)."""
+    expr's result cache. ``span`` is the flight recorder's dispatch
+    id, carried by the ``serve_batch`` span. Raises on failure — the
+    engine falls back to solo dispatches (where the resilience policy
+    engine handles classification, per-tenant budgets and retries)."""
     B = len(requests)
     order = plan.arg_order
     nargs = len(order)
@@ -188,7 +190,7 @@ def dispatch_batch(plan: Any, requests: List[Any], mesh) -> List[Any]:
     fresh = not ex.warm
     phase_name = "compile" if fresh else "dispatch"
     with prof.span("serve_batch", batch=B, mode=mode,
-                   plan=key_hash(plan.key)):
+                   plan=key_hash(plan.key), span=span):
         with prof.phase(phase_name):
             # same watchdog + chaos seams as expr/base._dispatch: a
             # hung batched dispatch dumps in-flight forensics, and an
@@ -218,9 +220,6 @@ def dispatch_batch(plan: Any, requests: List[Any], mesh) -> List[Any]:
         REGISTRY.counter(
             "serve_coalesced_batches",
             "coalesced batched dispatches").inc()
-        REGISTRY.histogram(
-            "serve:batch_size",
-            "clients per coalesced dispatch").observe(float(B))
     if plan.report is not None:
         sv = plan.report.setdefault(
             "serve", {"batches": 0, "requests": 0, "last_batch": None,

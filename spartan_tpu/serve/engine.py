@@ -368,7 +368,19 @@ class ServeEngine:
                tenant: Optional[str] = None,
                deadline_s: Optional[float] = None) -> EvalFuture:
         """Admit one evaluation; returns its future immediately.
-        Raises :class:`Backpressure` past the queue's high-water mark."""
+        Raises :class:`Backpressure` past the queue's high-water mark.
+        Traced, the ``serve_submit`` span covers signing and admission
+        on the caller's thread and carries the request's ``rid``."""
+        if not trace_mod._TRACE_FLAG._value:
+            return self._submit(expr, donate, tenant, deadline_s)
+        with trace_mod.span("serve_submit") as sp:
+            fut = self._submit(expr, donate, tenant, deadline_s)
+            sp.set(rid=fut.rid)
+        return fut
+
+    def _submit(self, expr: Any, donate: Sequence[Any],
+                tenant: Optional[str],
+                deadline_s: Optional[float]) -> EvalFuture:
         expr = base.as_expr(expr)
         gate = self._reconfiguring
         if gate is not None:
@@ -677,6 +689,7 @@ class ServeEngine:
         record = flight_mod._FLIGHT_FLAG._value
         for r in batch:
             r.t_dispatch = t0
+            r.future.dispatch_span = span
             if record:
                 flight_mod.note(r.rid, "coalesce", span=span,
                                 batch=len(batch), via=r.via)
@@ -690,7 +703,7 @@ class ServeEngine:
             with mesh_mod.use_mesh(batch[0].mesh), \
                     numerics_mod.deadline_scope(tightest):
                 results = coalesce.dispatch_batch(plan, batch,
-                                                  batch[0].mesh)
+                                                  batch[0].mesh, span)
         finally:
             self.ledger.release(reserved)
         for r, res in zip(batch, results):
@@ -702,24 +715,36 @@ class ServeEngine:
                         status: str) -> None:
         """One resolution record: the request's latency decomposition
         (queue-wait / coalesce-wait / dispatch) lands in its flight
-        record and the per-tenant histograms; the end-to-end latency
-        feeds the tenant's SLO class (obs/slo.py) regardless of the
-        flight-recorder flag."""
+        record and the per-tenant histograms, and, traced, in the
+        ``serve_queue`` / ``serve_linger`` spans (and ``serve_solo`` for
+        a dispatch of one), built from the request's stamps; the
+        end-to-end latency feeds the tenant's SLO class (obs/slo.py)
+        regardless of the flight-recorder flag."""
         if r.future.t_resolved is not None:
             slo_mod.observe(r.tenant,
                             r.future.t_resolved - r.t_submit)
+        t_taken = r.t_taken or r.t_submit
+        t_dispatch = r.t_dispatch or t_taken
+        if trace_mod._TRACE_FLAG._value:
+            trace_mod.record("serve_queue", r.t_submit, t_taken,
+                             rid=r.rid, span=span)
+            trace_mod.record("serve_linger", t_taken, t_dispatch,
+                             rid=r.rid, span=span)
+            if batch == 1:
+                trace_mod.record("serve_solo", t_dispatch,
+                                 r.future.t_resolved, rid=r.rid,
+                                 span=span)
         if not flight_mod._FLIGHT_FLAG._value:
             return
         flight_mod.record_resolution(
             rid=r.rid, tenant=r.tenant, span=span, batch=batch,
-            status=status, t_submit=r.t_submit,
-            t_taken=r.t_taken or r.t_submit,
-            t_dispatch=r.t_dispatch or r.t_taken or r.t_submit,
-            t_resolved=r.future.t_resolved)
+            status=status, t_submit=r.t_submit, t_taken=t_taken,
+            t_dispatch=t_dispatch, t_resolved=r.future.t_resolved)
 
     def _solo(self, r: _Request) -> None:
         span = flight_mod.mint_span()
         r.t_dispatch = trace_mod.now()
+        r.future.dispatch_span = span
         if flight_mod._FLIGHT_FLAG._value:
             flight_mod.note(r.rid, "dispatch", span=span, batch=1,
                             via=r.via)

@@ -20,6 +20,9 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable, List, Optional
 
+from ..obs import flight as flight_mod
+from ..obs import trace as trace_mod
+
 
 class ServeError(RuntimeError):
     """Base class for serving-layer failures."""
@@ -91,7 +94,8 @@ class EvalFuture:
     is actually awaited."""
 
     __slots__ = ("_event", "_result", "_exc", "_callbacks", "_lock",
-                 "tenant", "coalesced", "t_submit", "t_resolved", "rid")
+                 "tenant", "coalesced", "t_submit", "t_resolved", "rid",
+                 "dispatch_span", "_woken")
 
     def __init__(self, tenant: Optional[str] = None):
         self._event = threading.Event()
@@ -111,6 +115,9 @@ class EvalFuture:
         # and shared with every event of this request's lifecycle;
         # 0 = not a recorded request (bare futures)
         self.rid: int = 0
+        # the flight recorder's id of the dispatch that resolved it
+        self.dispatch_span: int = 0
+        self._woken = False  # serve_wake recorded (first return only)
 
     # -- caller side ----------------------------------------------------
 
@@ -123,6 +130,13 @@ class EvalFuture:
                 f"EvalFuture.result timed out after {timeout}s")
         if self._exc is not None:
             raise self._exc
+        if trace_mod._TRACE_FLAG._value and self.rid and not self._woken:
+            # the serve_wake span: resolution on the worker -> this
+            # caller running again
+            self._woken = True
+            trace_mod.record("serve_wake", self.t_resolved,
+                             trace_mod.now(), rid=self.rid,
+                             span=self.dispatch_span)
         return self._result
 
     def exception(self, timeout: Optional[float] = None
@@ -135,20 +149,19 @@ class EvalFuture:
     def glom(self, timeout: Optional[float] = None) -> Any:
         """Resolve AND fetch: the one call that blocks on device
         execution (``result()`` returns an async array handle). The
-        fetch wall time is the last hop of this request's flight
-        record (per-tenant ``serve_fetch_s`` histogram)."""
-        out = self.result(timeout)
-        from ..obs import flight as flight_mod
-        from ..obs import trace as trace_mod
+        wall time of its ``fetch`` spans is the last hop of this
+        request's flight record (per-tenant ``serve_fetch_s``
+        histogram)."""
+        from ..array.distarray import fetch_to_host
 
-        t0 = trace_mod.now()
-        if isinstance(out, tuple):
-            fetched: Any = tuple(o.glom() for o in out)
-        else:
-            fetched = out.glom()
+        out = self.result(timeout)
+        parts = [fetch_to_host(o.jax_array)
+                 for o in (out if isinstance(out, tuple) else (out,))]
         flight_mod.note_fetch(self.rid, self.tenant,
-                              trace_mod.now() - t0)
-        return fetched
+                              sum(s for _, s in parts))
+        if isinstance(out, tuple):
+            return tuple(host for host, _ in parts)
+        return parts[0][0]
 
     def add_done_callback(self, fn: Callable[["EvalFuture"], None]
                           ) -> None:
@@ -180,8 +193,6 @@ class EvalFuture:
                 pass  # client callbacks must not kill the worker
 
     def _stamp(self) -> None:
-        from ..obs import trace as trace_mod
-
         self.t_resolved = trace_mod.now()
 
     def _resolve(self, result: Any) -> None:

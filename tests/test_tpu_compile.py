@@ -9,6 +9,7 @@ fixture, never at import, so every test worker collects the same tests.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -176,3 +177,53 @@ def test_sort_exchange_four_chips(topo, mosaic):
     txt = compiled.as_text()
     assert "all-to-all" in txt and "ragged-all-to-all" not in txt
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def _named_kernel_text(topo, kernel: str) -> str:
+    """A small program around one Pallas kernel, compiled for one
+    described chip."""
+    mesh = _mesh(topo, (1, 1))
+    if kernel == "kmeans_lloyd":
+        from spartan_tpu.kernels import kmeans as kk
+        from spartan_tpu.parallel import mesh as mesh_mod
+
+        with mesh_mod.use_mesh(mesh):
+            return kk.run.lower(_sds((8192, 128), F32, mesh),
+                                _sds((16, 128), F32, mesh), k=16,
+                                iters=_sds((), I32, mesh)
+                                ).compile().as_text()
+    if kernel == "windowed_segsum":
+        from spartan_tpu.kernels.segment import windowed_segsum
+
+        static, grand = _windowed_shapes(1 << 16, 1 << 14)
+        return _compiled_text(
+            windowed_segsum, _sds((grand,), F32, mesh),
+            _sds((grand // 128, 128), I32, mesh),
+            _sds((grand // 1024,), I32, mesh), **static)
+    if kernel == "segment_sum_block":
+        from spartan_tpu.kernels.segment import segment_sum_block
+
+        return _compiled_text(segment_sum_block, _sds((8192,), F32, mesh),
+                              _sds((8192,), I32, mesh), num_segments=64,
+                              interpret=False)
+    if kernel == "bincount_block":
+        from spartan_tpu.kernels.histogram import bincount_block
+
+        return _compiled_text(bincount_block, _sds((8192,), I32, mesh),
+                              length=64, interpret=False)
+    from spartan_tpu.kernels.stencil import conv_block
+
+    return _compiled_text(conv_block, _sds((1, 18, 18, 128), F32, mesh),
+                          _sds((3, 3, 128, 128), F32, mesh), hb=8,
+                          interpret=False)
+
+
+@pytest.mark.parametrize("kernel", ["kmeans_lloyd", "windowed_segsum",
+                                    "segment_sum_block", "bincount_block",
+                                    "conv_block"])
+def test_kernel_carries_its_name(topo, mosaic, kernel):
+    """The compiled custom call is named by its ``pallas_call``'s
+    ``name``, which the device trace's op events carry."""
+    txt = _named_kernel_text(topo, kernel)
+    assert re.search(rf"%{kernel}(\.\d+)? = .*custom_call_target="
+                     r'"tpu_custom_call"', txt), kernel
