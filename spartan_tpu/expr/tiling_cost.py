@@ -45,30 +45,43 @@ from .slice import SliceExpr
 # output size) and forced a hand-chosen override here.
 _COMPUTE_WEIGHT = 4.0
 
-# Bytes-equivalent cost of one contraction FLOP: (sec/FLOP) divided by
-# (sec/interconnect-byte). Measured by calibrate_flop_weight — a local
-# matmul timed against a ring all-gather on the same mesh — and
-# recorded per platform; the cpu value is the committed calibration
-# from benchmarks/tiling_sweep.json (regenerate with tiling_ab.py
-# --sweep), the tpu value derives from spec ratios (~200 bf16 TFLOP/s
-# MXU vs ~4.5e10 B/s ICI per link) pending on-pod calibration.
-# Override with --tiling_flop_weight.
-_FLOP_WEIGHT_DEFAULTS = {"cpu": 0.005, "tpu": 2.5e-4}
+# Per-platform contraction weights, both in units of one output psum
+# byte, both CALIBRATED by a measured-arm sweep (benchmarks/tiling_ab.py
+# --sweep: every candidate plan of each GEMM layout combo forced as an
+# arm and timed). Override with --tiling_flop_weight and
+# --tiling_operand_move_weight.
+#
+# Flop weight: bytes-equivalent cost of one contraction FLOP.
+#  * cpu: calibrate_flop_weight (a local matmul timed against a ring
+#    all-gather) on the 8-device CPU mesh, benchmarks/tiling_sweep.json.
+#  * tpu: fitted with the operand-move weight below to every plan's
+#    measured time on a v5e 2x2, benchmarks/tiling_sweep_tpu.json.
+_FLOP_WEIGHT_DEFAULTS = {"cpu": 0.005, "tpu": 1.2e-4}
 _FLOP_WEIGHT_FALLBACK = 1e-3
 
-# Weight on operand-reshard bytes in contraction plans, relative to
-# output psum bytes. Operand gathers sit on the critical path BEFORE
-# the matmul and replicate operand memory, while the output all-reduce
-# pipelines with the epilogue — so a byte of operand movement costs
-# more wall time than a byte of psum. CALIBRATED by the measured-arm
-# sweep (benchmarks/tiling_ab.py --sweep, 8 GEMM layout combos + 2
-# einsum batched-matmul combos x all candidate plans on the 8-device
-# CPU mesh): under receive-bytes reshard pricing, weights 4 and 5 both
-# bring EVERY combo's pick within 20% of its best measured arm
-# (including row_t x row_t, the round-4 residual — now 1.00); 5
-# measured best overall (max pick/best 1.145, within run noise).
-# Override with --tiling_operand_move_weight.
-_OPERAND_MOVE_WEIGHT = 5.0
+# Operand-move weight: a byte of operand reshard against a byte of
+# output psum. Operand moves sit on the critical path before the
+# matmul and replicate operand memory.
+#  * cpu: 8 GEMM layout combos + 2 einsum batched-matmul combos on the
+#    8-device CPU mesh (benchmarks/tiling_sweep.json): weights 4 and 5
+#    both bring every combo's pick within 20% of its best arm; 5
+#    measured best (max pick/best 1.145, within run noise).
+#  * tpu: on a v5e 2x2 (benchmarks/tiling_sweep_tpu.json: 5 GEMM
+#    layout combos + 1 batched einsum, at 4096^2 and 8192^2, every
+#    output tiling x contraction placement an arm) a least-squares fit
+#    of the arms' times on psum bytes, operand bytes moved (at the
+#    width they move, _moved_width) and FLOPs a chip, with an
+#    intercept per combo, gives 1.219 and 1.17e-4: an exposed
+#    all-reduce costs about what an operand gather does. Rounded, they
+#    bring every combo's pick within 6.1% of its best arm (max
+#    pick/best 1.061, was 1.839 at 5 / 2.5e-4 and f32 move bytes).
+_OPERAND_MOVE_WEIGHT_DEFAULTS = {"cpu": 5.0, "tpu": 1.2}
+_OPERAND_MOVE_WEIGHT_FALLBACK = 5.0
+
+# Precisions at which XLA:TPU multiplies float32 operands in a single
+# bf16 pass. It then converts them before any collective, so their
+# moves cross the interconnect at 2 bytes an element.
+_ONE_PASS_PRECISIONS = ("default", "bfloat16", "fastest")
 
 # Tie-break epsilon on the same quantity: keeps plan choice
 # deterministic on exact byte ties regardless of the weight above.
@@ -278,23 +291,59 @@ def _compute_weight() -> float:
     return w if w > 0 else _COMPUTE_WEIGHT
 
 
-def _flop_weight() -> float:
+def _platform(mesh=None) -> str:
+    """The platform the plan runs on: the mesh's devices (a mesh of a
+    described, unattached chip included), else JAX's backend."""
+    devices = getattr(mesh, "devices", None)
+    if devices is not None and np.size(devices):
+        return np.asarray(devices).flat[0].platform
+    import jax
+
+    return jax.default_backend()
+
+
+def _flop_weight(mesh=None) -> float:
     from ..utils.config import FLAGS
 
     w = float(getattr(FLAGS, "tiling_flop_weight", 0.0) or 0.0)
     if w > 0:
         return w
-    import jax
-
-    return _FLOP_WEIGHT_DEFAULTS.get(jax.default_backend(),
+    return _FLOP_WEIGHT_DEFAULTS.get(_platform(mesh),
                                      _FLOP_WEIGHT_FALLBACK)
 
 
-def _operand_move_weight() -> float:
+def _operand_move_weight(mesh=None) -> float:
     from ..utils.config import FLAGS
 
     w = float(getattr(FLAGS, "tiling_operand_move_weight", 0.0) or 0.0)
-    return w if w > 0 else _OPERAND_MOVE_WEIGHT
+    if w > 0:
+        return w
+    return _OPERAND_MOVE_WEIGHT_DEFAULTS.get(_platform(mesh),
+                                             _OPERAND_MOVE_WEIGHT_FALLBACK)
+
+
+def _one_pass_bf16(precision) -> bool:
+    """Whether a contraction at ``precision`` multiplies in one bf16
+    pass (None defers to ``jax_default_matmul_precision``)."""
+    if precision is None:
+        import jax
+
+        precision = jax.config.jax_default_matmul_precision
+        if precision is None:
+            return True
+    name = getattr(precision, "name", precision)
+    return str(name).lower() in _ONE_PASS_PRECISIONS
+
+
+def _moved_width(node: Expr, platform: str) -> Tuple[float, float]:
+    """Per operand of a contraction, the share of its bytes that
+    crosses the interconnect when it moves: on TPU at a one-pass
+    precision a float32 operand is converted to bf16 before it is
+    gathered, so it moves half its bytes; else all of them."""
+    if platform != "tpu" or not _one_pass_bf16(node.precision):
+        return 1.0, 1.0
+    return tuple(0.5 if c.dtype == np.float32 else 1.0
+                 for c in node.children()[:2])
 
 
 def _memory_weight() -> float:
@@ -314,8 +363,9 @@ def _build_table(root: Expr, mesh) -> Dict:
     where strategy is the chosen contraction placement for GEMMs."""
     table: Dict[int, Dict[Tiling, Tuple[float, Tuple, Optional[str]]]] = {}
     weight = _compute_weight()
-    flop_w = _flop_weight()
-    move_w = _operand_move_weight()
+    flop_w = _flop_weight(mesh)
+    move_w = _operand_move_weight(mesh)
+    platform = _platform(mesh)
     mem_w = _memory_weight()
     # profile-guided calibration (obs/ledger): per-op-class factors
     # multiply the matching cost terms; identity when no profile is
@@ -347,19 +397,21 @@ def _build_table(root: Expr, mesh) -> Dict:
             return redist_mod.edge_cost(tc, req, nb, mesh, cal)
         return reshard_cost(tc, req, nb, mesh)
 
-    def best_child(c: Expr, req: Optional[Tiling], w: float = 1.0
+    def best_child(c: Expr, req: Optional[Tiling], w: float = 1.0,
+                   width: float = 1.0
                    ) -> Tuple[float, Optional[Tiling], float]:
         """Cheapest child entry under requirement ``req``, with the
-        reshard move charged at weight ``w`` (GEMM operand moves use
-        _OPERAND_MOVE_WEIGHT so selection and plan pricing agree —
-        otherwise a reshard-heavy child could win selection at weight
-        1 and then be priced at w). Returns (total, pick, move)."""
+        reshard move of ``width`` x the child's bytes charged at
+        weight ``w`` (GEMM operand moves use the operand-move weight
+        so selection and plan pricing agree — otherwise a
+        reshard-heavy child could win selection at weight 1 and then
+        be priced at w). Returns (total, pick, move)."""
         best_cost = None
         best_pick = None
         best_move = 0.0
         for tc, entry in table[c._id].items():
             move = (0.0 if req is None
-                    else move_cost(tc, req, nbytes(c)))
+                    else move_cost(tc, req, nbytes(c) * width))
             total = entry[0] + w * move
             # on a total tie prefer the lower-move entry, so the move
             # fed into the _OP_MOVE_EPS tie-break is itself
@@ -398,15 +450,16 @@ def _build_table(root: Expr, mesh) -> Dict:
                 # (2mnk-style, _flop_weight): a sharded contraction
                 # multiplies the parallelism by the strategy axis.
                 flops, reqs_fn, has_contraction = cview
+                width_a, width_b = _moved_width(node, platform)
                 best = None
                 strategies = (_dot_strategies(t, mesh)
                               if has_contraction else [None])
                 for s in strategies:
                     req_a, req_b = reqs_fn(t, s)
                     ca, pa, ma = best_child(kids[0], req_a,
-                                            move_w * move_unit)
+                                            move_w * move_unit, width_a)
                     cb, pb, mb = best_child(kids[1], req_b,
-                                            move_w * move_unit)
+                                            move_w * move_unit, width_b)
                     psum = 0.0
                     if s is not None:
                         # ring all-reduce of each chip's PARTIAL — the
@@ -421,8 +474,8 @@ def _build_table(root: Expr, mesh) -> Dict:
                           / (_parallelism(t, mesh) * _axis_size(mesh, s)))
                     # operand movement is charged at move_w inside
                     # best_child (critical path before the matmul —
-                    # see _OPERAND_MOVE_WEIGHT); the epsilon keeps
-                    # exact ties deterministic
+                    # see _OPERAND_MOVE_WEIGHT_DEFAULTS); the epsilon
+                    # keeps exact ties deterministic
                     tot = (ca + cb + psum + fl + memcost
                            + (ma + mb) * _OP_MOVE_EPS)
                     if best is None or tot < best[0]:
@@ -550,8 +603,9 @@ def class_components(root: Expr, mesh=None) -> Dict[str, float]:
     if _mesh_n(mesh) <= 1:
         return {}
     weight = _compute_weight()
-    flop_w = _flop_weight()
-    move_w = _operand_move_weight()
+    flop_w = _flop_weight(mesh)
+    move_w = _operand_move_weight(mesh)
+    platform = _platform(mesh)
     # planner on: reshard edges decompose into their chosen schedule's
     # per-collective bytes (all_gather / all_to_all) and psum into its
     # reduce-scatter + all-gather halves, so fit_profile calibrates
@@ -563,14 +617,15 @@ def class_components(root: Expr, mesh=None) -> Dict[str, float]:
         if v:
             comp[cls] = comp.get(cls, 0.0) + float(v)
 
-    def move(child: Expr, req: Optional[Tiling], w: float) -> None:
+    def move(child: Expr, req: Optional[Tiling], w: float,
+             width: float = 1.0) -> None:
         if req is None:
             return
         try:
             src = child.out_tiling()
         except Exception:
             return
-        nb = float(child.size) * child.dtype.itemsize
+        nb = float(child.size) * child.dtype.itemsize * width
         if planner:
             for cls, v in redist_mod.edge_components(src, req, nb,
                                                      mesh).items():
@@ -613,8 +668,9 @@ def class_components(root: Expr, mesh=None) -> Dict[str, float]:
             except Exception:
                 reqs = None
             if reqs is not None:
-                for c, req in zip(kids, reqs):
-                    move(c, req, move_w)
+                for c, req, width in zip(kids, reqs,
+                                         _moved_width(n, platform)):
+                    move(c, req, move_w, width)
             continue
         add(op_class(n), nbytes * weight / _parallelism(t, mesh))
         for i, c in enumerate(kids):
@@ -647,7 +703,7 @@ def calibrate_flop_weight(n: int = 512, iters: int = 5,
     mesh = mesh or mesh_mod.get_mesh()
     p = _mesh_n(mesh)
     if p <= 1:
-        return _flop_weight()
+        return _flop_weight(mesh)
     x = jnp.asarray(np.random.RandomState(0).rand(n, n).astype(np.float32))
     mm = jax.jit(lambda a: a @ a)
     jax.block_until_ready(mm(x))
@@ -666,7 +722,7 @@ def calibrate_flop_weight(n: int = 512, iters: int = 5,
             jax.block_until_ready(gather(xs))
     t_ag = sw.elapsed / iters
     if t_ag <= 0:
-        return _flop_weight()
+        return _flop_weight(mesh)
     flops = 2.0 * n * n * n
     ag_bytes = float(n) * n * x.dtype.itemsize * (p - 1) / p
     return float((t_mm / flops) / (t_ag / ag_bytes))

@@ -264,3 +264,59 @@ def test_planner_flag_changes_dp_edge_prices(mesh2d):
     sched = rd.schedules(src, dst, m)
     assert any(s.steps[0].kind == "all_to_all" and len(s.steps) == 1
                for s in sched)
+
+
+# -- contraction pricing per platform ------------------------------------
+
+
+def _shape_only_leaf(n, axes, dtype=np.float32):
+    """An n x n operand the planner sees by shape, dtype and tiling
+    alone: nothing is allocated."""
+    from types import SimpleNamespace
+
+    from spartan_tpu.expr.base import ValExpr
+
+    return ValExpr(SimpleNamespace(shape=(n, n), dtype=np.dtype(dtype),
+                                   tiling=tiling.Tiling(axes)))
+
+
+@pytest.mark.parametrize("platform,plan", [
+    # the chip gathers bf16 panels: the gathered (x, y) plan, no psum
+    ("tpu", (("x", "y"), None)),
+    # the CPU mesh's pick is what it was before platforms were told
+    # apart: rows on x, the contraction sharded on y
+    ("cpu", (("x", None), "y")),
+])
+def test_gemm_8192_plan_per_platform(monkeypatch, platform, plan):
+    """The dot_8192 cell's GEMM — 8192^2 f32 operands tiled (x, y) on
+    a 2x2 mesh, default precision — is planned as its platform runs
+    it."""
+    from spartan_tpu.expr import tiling_cost
+
+    monkeypatch.setattr(tiling_cost, "_platform",
+                        lambda mesh=None: platform)
+    m = mesh_mod.build_mesh(mesh_mod.jax.devices()[:4], shape=(2, 2))
+    with mesh_mod.use_mesh(m):
+        d = assign_tilings(st.dot(_shape_only_leaf(8192, ("x", "y")),
+                                  _shape_only_leaf(8192, ("x", "y"))))
+    assert (d._dot_plan[0].axes, d._dot_plan[1]) == plan
+
+
+@pytest.mark.parametrize("platform,precision,dtype,width", [
+    ("tpu", None, np.float32, 0.5),
+    ("tpu", "default", np.float32, 0.5),
+    ("tpu", "highest", np.float32, 1.0),
+    ("tpu", "high", np.float32, 1.0),
+    ("tpu", None, np.int32, 1.0),
+    ("cpu", None, np.float32, 1.0),
+])
+def test_operand_moved_width(platform, precision, dtype, width):
+    """Operand moves are priced at the width the chip moves them: on
+    TPU at a one-pass precision a float32 operand crosses the
+    interconnect as bf16; at HIGH or HIGHEST, or off TPU, in full."""
+    from spartan_tpu.expr.dot import DotExpr
+    from spartan_tpu.expr.tiling_cost import _moved_width
+
+    d = DotExpr(_shape_only_leaf(64, (None, None), dtype),
+                _shape_only_leaf(64, (None, None), dtype), precision)
+    assert _moved_width(d, platform) == (width, width)

@@ -227,3 +227,34 @@ def test_kernel_carries_its_name(topo, mosaic, kernel):
     txt = _named_kernel_text(topo, kernel)
     assert re.search(rf"%{kernel}(\.\d+)? = .*custom_call_target="
                      r'"tpu_custom_call"', txt), kernel
+
+
+def test_dot_8192_committed_plan(topo):
+    """The dot_8192 cell's product — 8192^2 f32 tiled (x, y) on the
+    2x2 mesh, default precision — lowered as the smart-tiling pass
+    commits it for the described chip: the operands' panels gathered
+    in bf16, no all-reduce of a partial product, the output (x, y)."""
+    from types import SimpleNamespace
+
+    from spartan_tpu.expr.base import ValExpr
+    from spartan_tpu.expr.dot import DotExpr
+    from spartan_tpu.expr.tiling_cost import assign_tilings
+    from spartan_tpu.parallel import mesh as mesh_mod
+
+    n = 8192
+    mesh = _mesh(topo, (2, 2))
+    xy = P(tiling_mod.AXIS_ROW, tiling_mod.AXIS_COL)
+    a, b = (ValExpr(SimpleNamespace(shape=(n, n), dtype=np.dtype(F32),
+                                    tiling=tiling_mod.Tiling(tuple(xy))))
+            for _ in range(2))
+    with mesh_mod.use_mesh(mesh):
+        d = assign_tilings(DotExpr(a, b))
+        compiled = jax.jit(lambda x, y: d.lower({a._id: x, b._id: y})
+                           ).lower(_sds((n, n), F32, mesh, xy),
+                                   _sds((n, n), F32, mesh, xy)).compile()
+    txt = compiled.as_text()
+    assert "all-reduce" not in txt
+    gathers = sorted(m.group(1) for m in re.finditer(
+        r"= (\w+\[[\d,]+\])\S* all-gather\(", txt))
+    assert gathers == ["bf16[4096,8192]", "bf16[8192,4096]"], gathers
+    assert compiled.output_shardings.spec == xy
