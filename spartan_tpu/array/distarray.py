@@ -542,19 +542,23 @@ class DistArray:
 # -- host transfers -----------------------------------------------------
 
 
-def fetch_to_host(x: jax.Array) -> Tuple[np.ndarray, float]:
-    """``x`` as a host array, and the wall seconds of its ``fetch``
-    phase. The device->host copy is enqueued first, right behind the
-    computation; the wait for the computation is the ``fetch_wait``
-    span inside ``fetch``, and what follows it is the rest of the
-    transfer and the conversion."""
+def fetch_to_host(x: Any) -> Tuple[Any, float]:
+    """``x`` (an array, or a tuple of arrays) on the host, and the wall
+    seconds of its one ``fetch`` phase. Every device->host copy is
+    enqueued first, right behind the computation; the wait for the
+    computation is the ``fetch_wait`` span inside ``fetch``, and what
+    follows it is the rest of the transfers and the conversions."""
+    leaves, tree = jax.tree.flatten(x)
     ctx = prof.phase("fetch")
     with ctx as sp:
-        sp.set(shape=tuple(x.shape), dtype=str(x.dtype))
-        x.copy_to_host_async()
+        sp.set(shape=tree.unflatten([tuple(e.shape) for e in leaves]),
+               dtype=tree.unflatten([str(e.dtype) for e in leaves]))
+        for e in leaves:
+            e.copy_to_host_async()
         with prof.span("fetch_wait"):
-            x.block_until_ready()
-        out = np.asarray(jax.device_get(x))
+            for e in leaves:
+                e.block_until_ready()
+        out = tree.unflatten([np.asarray(e) for e in leaves])
     return out, ctx.seconds
 
 

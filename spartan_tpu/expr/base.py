@@ -604,7 +604,9 @@ class TupleExpr(Expr):
         return evaluate(self, donate=donate)
 
     def glom(self):  # type: ignore[override]
-        return tuple(r.glom() for r in evaluate(self))
+        """Every root on the host, from one ``fetch``."""
+        return da.fetch_to_host(tuple(r.jax_array
+                                      for r in evaluate(self)))[0]
 
 
 def tuple_of(*elements: Any) -> TupleExpr:
@@ -615,7 +617,7 @@ class ListExpr(TupleExpr):
     """List-shaped multi-root evaluation (reference's ``ListExpr``)."""
 
     def glom(self):  # type: ignore[override]
-        return [r.glom() for r in evaluate(self)]
+        return list(TupleExpr.glom(self))
 
 
 class DictExpr(Expr):
@@ -651,7 +653,7 @@ class DictExpr(Expr):
         return self.evaluate(donate=donate)
 
     def glom(self):  # type: ignore[override]
-        return {k: v.glom() for k, v in self.evaluate().items()}
+        return dict(zip(self._keys, self._tuple.glom()))
 
     def __getitem__(self, key: str) -> Expr:  # type: ignore[override]
         return self._tuple.elements[self._keys.index(key)]

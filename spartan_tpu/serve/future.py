@@ -149,19 +149,17 @@ class EvalFuture:
     def glom(self, timeout: Optional[float] = None) -> Any:
         """Resolve AND fetch: the one call that blocks on device
         execution (``result()`` returns an async array handle). The
-        wall time of its ``fetch`` spans is the last hop of this
-        request's flight record (per-tenant ``serve_fetch_s``
-        histogram)."""
+        wall time of its one ``fetch`` span, a tuple result's too, is
+        the last hop of this request's flight record (per-tenant
+        ``serve_fetch_s`` histogram)."""
         from ..array.distarray import fetch_to_host
 
         out = self.result(timeout)
-        parts = [fetch_to_host(o.jax_array)
-                 for o in (out if isinstance(out, tuple) else (out,))]
-        flight_mod.note_fetch(self.rid, self.tenant,
-                              sum(s for _, s in parts))
-        if isinstance(out, tuple):
-            return tuple(host for host, _ in parts)
-        return parts[0][0]
+        host, seconds = fetch_to_host(
+            tuple(o.jax_array for o in out) if isinstance(out, tuple)
+            else out.jax_array)
+        flight_mod.note_fetch(self.rid, self.tenant, seconds)
+        return host
 
     def add_done_callback(self, fn: Callable[["EvalFuture"], None]
                           ) -> None:
