@@ -342,12 +342,10 @@ def _kernel_plans_on_tpu(st) -> dict:
     links = st.SparseDistArray.from_coo(
         rows, rng.integers(0, n, 4 * n), np.ones(4 * n, np.float32), (n, n))
     T = links.transition()
-    plan = T._ensure_plan()
+    bufs, dims = T._windowed_plan()
     pg = _custom_call_in(
-        pr._pagerank_loop, T._pdata, T._pcols, plan._ids2d, plan._wb,
-        jnp.full((n,), 1.0 / n, f32), f32(0.85), jnp.int32(1), n=n,
-        num_segments=plan.num_segments, rows_pad=plan.rows_pad,
-        nsteps=plan.nsteps, outblk=plan.outblk, sub=plan.SUB)
+        pr._pagerank_loop, bufs, jnp.full((n,), 1.0 / n, f32), f32(0.85),
+        jnp.int32(1), n=n, dims=dims)
     return {"kmeans_tpu_custom_call": km, "pagerank_tpu_custom_call": pg}
 
 

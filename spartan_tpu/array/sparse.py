@@ -8,9 +8,10 @@ SURVEY.md §7 hard part 2: *static* nse (padded), entries lexicographically
 stored as three device arrays (data, rows, cols) sharded along the entry
 axis. SpMV is ``segment_sum(data * x[cols], rows)`` — the scatter-merge
 runs through :mod:`spartan_tpu.ops.segment` (the Pallas/XLA merge
-kernels), and a BCOO bridge exposes ``jax.experimental.sparse`` fast
-paths. Padding entries carry ``row = nrows`` so every merge drops them
-(XLA segment semantics).
+kernels); on one chip the windowed path runs the gather as a Pallas
+kernel too (``ops.segment.windowed_spmv``), and a BCOO bridge exposes
+``jax.experimental.sparse`` fast paths. Padding entries carry
+``row = nrows`` so every merge drops them (XLA segment semantics).
 """
 
 from __future__ import annotations
@@ -22,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.segment import segment_sum
+from ..ops.segment import SegmentPlan, segment_sum, windowed_spmv
 from ..parallel import mesh as mesh_mod
 from ..utils import profiling as prof
 from . import tiling as tiling_mod
@@ -74,19 +75,12 @@ def _rsums_kernel(data, rows, *, n):
     return segment_sum(data, rows, n, sorted_ids=True)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "num_segments", "rows_pad", "nsteps", "outblk", "sub"))
-def _windowed_spmv_jit(pdata, pcols, ids2d, wb, x, *, num_segments,
-                       rows_pad, nsteps, outblk, sub):
+@functools.partial(jax.jit, static_argnames=("dims",))
+def _windowed_spmv_jit(bufs, x, *, dims):
     """Module-level jitted windowed spmv: plan buffers enter as traced
-    arguments, so same-dimension matrices share one Mosaic compile
-    (these compiles run minutes) and nothing pins device memory."""
-    from ..ops.segment import _windowed_segsum
-
-    out2d = _windowed_segsum(pdata * x[pcols], ids2d, wb,
-                             rows_pad=rows_pad, nsteps=nsteps,
-                             outblk=outblk, sub=sub)
-    return out2d.reshape(-1)[:num_segments]
+    arguments, so same-dimension matrices share one Mosaic compile and
+    nothing pins device memory."""
+    return windowed_spmv(*bufs, x, dims)
 
 
 @jax.jit
@@ -245,10 +239,9 @@ class SparseDistArray:
         self.nnz = int(nnz)  # true (unpadded) count
         self.mesh = mesh or mesh_mod.get_mesh()
         # windowed-kernel layout (ops/segment.SegmentPlan), built lazily:
-        # plan + plan-ordered data/cols device arrays + jitted kernels
+        # the plan and the plan-ordered data
         self._plan = None
         self._pdata = None
-        self._pcols = None
         # cached column-stochastic transition (see transition())
         self._transition: Optional["SparseDistArray"] = None
 
@@ -374,39 +367,49 @@ class SparseDistArray:
 
     # -- ops ------------------------------------------------------------
 
-    # segment-plan scratch must fit VMEM: ~4 bytes/row, <=2M rows
+    # the windowed kernels hold the output (4 bytes a row) and x (6 bytes
+    # a column, in three bf16 parts) in VMEM
     _PLAN_MAX_ROWS = 2 * 1024 * 1024
+    _PLAN_MAX_COLS = SegmentPlan.MAX_COLS
 
     def _ensure_plan(self):
         """Build (once) the windowed-kernel layout: a SegmentPlan over
-        the sorted row ids plus plan-ordered data/cols device arrays."""
+        the sorted row ids and their columns, and the data in plan
+        order on the device."""
         if self._plan is not None:
             return self._plan
-        from ..ops.segment import SegmentPlan
-
-        with prof.span("segment_plan", entries=self.nse):
+        with prof.span("segment_plan", entries=self.nse) as sp:
             rows = fetch_to_host(self.rows)[0]
             data = fetch_to_host(self.data)[0]
             cols = fetch_to_host(self.cols)[0]
-            plan = SegmentPlan(rows, self.shape[0])
+            plan = SegmentPlan(rows, self.shape[0], cols=cols,
+                               num_cols=self.shape[1])
             pdata = plan.reorder(data)
-            pcols = plan.reorder(cols, fill=0).astype(np.int32)
             with upload_span(pdata):
                 self._pdata = jnp.asarray(pdata)
-            with upload_span(pcols):
-                self._pcols = jnp.asarray(pcols)
+            sp.set(padded=plan.padded_size, groups=plan.groups)
         self._plan = plan
         return plan
 
+    def _windowed_plan(self) -> tuple:
+        """``(buffers, dims)`` for ``ops.segment.windowed_spmv``: the
+        plan's device buffers in its argument order (``x`` left out)
+        and its static dims."""
+        plan = self._ensure_plan()
+        return ((self._pdata, plan._lcols, plan._gwin, plan._ids2d,
+                 plan._wb), plan.dims)
+
     def _can_window(self) -> bool:
-        """Structural feasibility of the windowed kernel: single-device
-        only (the plan gathers entries to host and the pallas_call is
+        """Structural feasibility of the windowed kernels: single-device
+        only (the plan gathers entries to host and the pallas_calls are
         not partitionable — on a multi-chip mesh the distributed
-        BCOO/segment paths stay the default) and within the VMEM row
-        bound. On non-TPU backends a *forced* impl='windowed' runs the
-        kernel in Pallas interpret mode (the test path); it is only
-        chosen by default when real Pallas TPU is present."""
+        BCOO/segment paths stay the default) and within the VMEM bound
+        on rows and columns. On non-TPU backends a *forced*
+        impl='windowed' runs the kernels in Pallas interpret mode (the
+        test path); it is only chosen by default when real Pallas TPU
+        is present."""
         return (self.shape[0] <= self._PLAN_MAX_ROWS
+                and self.shape[1] <= self._PLAN_MAX_COLS
                 and mesh_mod.device_count(self.mesh) == 1)
 
     def _default_windowed(self) -> bool:
@@ -428,10 +431,10 @@ class SparseDistArray:
         """Windowed-kernel matvec, traceable inside any jit (including
         ``lax.fori_loop`` bodies, where XLA's own scatter lowering
         collapses — measured 2.7 s/iter vs ~170 ms for this path at 16M
-        entries on v5e). Requires a plan (see :meth:`_ensure_plan`)."""
-        plan = self._ensure_plan()
-        contrib = self._pdata * x[self._pcols]
-        return plan.segment_sum(contrib)
+        entries on v5e). Builds the plan on first use (see
+        :meth:`_ensure_plan`)."""
+        bufs, dims = self._windowed_plan()
+        return windowed_spmv(*bufs, x, dims)
 
     def spmv(self, x: Any, impl: Optional[str] = None) -> jax.Array:
         """y = A @ x for dense x (n,) or (n, d).
@@ -458,13 +461,11 @@ class SparseDistArray:
                 raise ValueError(
                     "impl='windowed' requested but the windowed kernel "
                     "is structurally unavailable here (needs a single-"
-                    f"device mesh and <= {self._PLAN_MAX_ROWS} rows); "
+                    f"device mesh, <= {self._PLAN_MAX_ROWS} rows and "
+                    f"<= {self._PLAN_MAX_COLS} columns); "
                     "use impl='bcoo' or leave impl=None")
-            plan = self._ensure_plan()
-            return _windowed_spmv_jit(
-                self._pdata, self._pcols, plan._ids2d, plan._wb, x,
-                num_segments=plan.num_segments, rows_pad=plan.rows_pad,
-                nsteps=plan.nsteps, outblk=plan.outblk, sub=plan.SUB)
+            bufs, dims = self._windowed_plan()
+            return _windowed_spmv_jit(bufs, x, dims=dims)
         if impl == "bcoo":
             return _spmv_bcoo_kernel(self.data, self.rows, self.cols, x,
                                      shape=self.shape)
@@ -501,7 +502,6 @@ class SparseDistArray:
         self._transition = None
         self._plan = None
         self._pdata = None
-        self._pcols = None
 
     def transpose(self) -> "SparseDistArray":
         """Transposed copy, entirely on device (argsort-by-key via a

@@ -74,20 +74,15 @@ def pagerank(links: SparseDistArray, damping: float = 0.85,
     return fetch_to_host(rank)[0]
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "n", "num_segments", "rows_pad", "nsteps", "outblk", "sub"))
-def _pagerank_loop(pdata, pcols, ids2d, wb, rank, damp, iters, *,
-                   n, num_segments, rows_pad, nsteps, outblk, sub):
+@functools.partial(jax.jit, static_argnames=("n", "dims"))
+def _pagerank_loop(bufs, rank, damp, iters, *, n, dims):
     """Module-level jit: plan buffers are traced arguments, so matrices
-    with the same plan dimensions share one compile (the Pallas-in-loop
-    program costs ~2 min to build) and nothing pins device memory."""
-    from ..ops.segment import _windowed_segsum
+    with the same plan dimensions share one compile and nothing pins
+    device memory."""
+    from ..ops.segment import windowed_spmv
 
     def body(_, r):
-        out2d = _windowed_segsum(pdata * r[pcols], ids2d, wb,
-                                 rows_pad=rows_pad, nsteps=nsteps,
-                                 outblk=outblk, sub=sub)
-        return _teleport_body(out2d.reshape(-1)[:num_segments], damp, n)
+        return _teleport_body(windowed_spmv(*bufs, r, dims), damp, n)
 
     return jax.lax.fori_loop(0, iters, body, rank)
 
@@ -95,9 +90,6 @@ def _pagerank_loop(pdata, pcols, ids2d, wb, rank, damp, iters, *,
 def _pagerank_fused(T: SparseDistArray, rank, damp, num_iter: int):
     """One dispatch for the whole power iteration; the iteration count
     is a traced loop bound so every num_iter shares one compile."""
-    plan = T._ensure_plan()
-    return _pagerank_loop(
-        T._pdata, T._pcols, plan._ids2d, plan._wb, rank, damp,
-        jnp.int32(num_iter), n=T.shape[0],
-        num_segments=plan.num_segments, rows_pad=plan.rows_pad,
-        nsteps=plan.nsteps, outblk=plan.outblk, sub=plan.SUB)
+    bufs, dims = T._windowed_plan()
+    return _pagerank_loop(bufs, rank, damp, jnp.int32(num_iter),
+                          n=T.shape[0], dims=dims)

@@ -114,22 +114,55 @@ def test_als():
     assert err < 0.05
 
 
-def test_pagerank():
+def _star_links(n):
+    """Everyone links to node 0; node 0 links to node 1."""
     from spartan_tpu.array.sparse import SparseDistArray
-    from spartan_tpu.examples.pagerank import pagerank
 
-    # star graph: everyone links to node 0; node 0 links to node 1
-    n = 8
-    rows = np.arange(1, n)
-    cols = np.zeros(n - 1, np.int64)
-    rows = np.concatenate([rows, [0]])
-    cols = np.concatenate([cols, [1]])
-    links = SparseDistArray.from_coo(rows, cols,
-                                     np.ones(n, np.float32), (n, n))
-    ranks = pagerank(links, num_iter=40)
+    rows = np.concatenate([np.arange(1, n), [0]])
+    cols = np.concatenate([np.zeros(n - 1, np.int64), [1]])
+    return SparseDistArray.from_coo(rows, cols, np.ones(n, np.float32),
+                                    (n, n))
+
+
+def _check_star_ranks(ranks):
     assert ranks.argmax() == 0
     assert ranks[1] > ranks[2]  # node 1 gets node 0's rank
     np.testing.assert_allclose(ranks.sum(), 1.0, rtol=1e-3)
+
+
+# three column windows of the windowed gather (16,384 columns each)
+_STAR_N = 2 * 16384 + 8
+
+
+def test_pagerank():
+    from spartan_tpu.examples.pagerank import pagerank
+
+    _check_star_ranks(pagerank(_star_links(_STAR_N), num_iter=40))
+
+
+def test_pagerank_windowed(monkeypatch):
+    """The one-dispatch windowed power iteration (the chip's path; the
+    kernels in interpret mode here) on one device, against float64
+    power iterations on the same graph."""
+    import jax
+
+    from spartan_tpu.array.sparse import SparseDistArray
+    from spartan_tpu.examples.pagerank import pagerank
+
+    n = _STAR_N
+    monkeypatch.setattr(SparseDistArray, "_default_windowed",
+                        lambda self: True)
+    with st.use_mesh(st.build_mesh(jax.devices()[:1], shape=(1, 1))):
+        got = pagerank(_star_links(n), num_iter=40)
+    _check_star_ranks(got)
+    # node i > 0 gives all its rank to 0, node 0 all of its to 1
+    want = np.full(n, 1.0 / n)
+    for _ in range(40):
+        y = np.zeros(n)
+        y[0], y[1] = want[1:].sum(), want[0]
+        y = 0.85 * y + 0.15 / n
+        want = y + (1.0 - y.sum()) / n
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5 / n)
 
 
 def test_ssvd():

@@ -135,31 +135,35 @@ def test_from_coo_lex_sorted_with_padding():
 import jax.numpy as jnp
 
 
+def _plan_segment_sum(ids, n, vals):
+    """A plain segment-sum through a SegmentPlan (one column) and the
+    windowed merge kernel: interpret mode on CPU, Mosaic on TPU."""
+    from spartan_tpu.ops.segment import SegmentPlan, windowed_merge
+
+    plan = SegmentPlan(ids, n, cols=np.zeros(len(ids), np.int32),
+                       num_cols=1)
+    return np.asarray(jax.device_get(windowed_merge(
+        jnp.asarray(plan.reorder(vals)), plan._ids2d, plan._wb,
+        plan.dims)))
+
+
 def test_segment_plan_windowed():
     """Windowed sorted-segment kernel vs numpy oracle (interpret mode on
     CPU; the real Mosaic kernel on TPU)."""
-    from spartan_tpu.ops.segment import SegmentPlan
-
     rng = np.random.RandomState(3)
     n, e = 3000, 20000
     ids = np.sort(rng.randint(0, n, size=e).astype(np.int32))
     vals = rng.rand(e).astype(np.float32)
-    plan = SegmentPlan(ids, n)
-    out = np.asarray(jax.device_get(
-        plan.segment_sum(jnp.asarray(plan.reorder(vals)))))
+    out = _plan_segment_sum(ids, n, vals)
     expect = np.zeros(n, np.float32)
     np.add.at(expect, ids, vals)
     np.testing.assert_allclose(out, expect, rtol=2e-4, atol=1e-5)
 
 
 def test_segment_plan_drops_out_of_range():
-    from spartan_tpu.ops.segment import SegmentPlan
-
     ids = np.array([0, 1, 1, 5, 7, 9, 9], np.int32)
     vals = np.arange(1, 8, dtype=np.float32)
-    plan = SegmentPlan(ids, 6)  # ids 7, 9, 9 out of range
-    out = np.asarray(jax.device_get(
-        plan.segment_sum(jnp.asarray(plan.reorder(vals)))))
+    out = _plan_segment_sum(ids, 6, vals)  # ids 7, 9, 9 out of range
     expect = np.zeros(6, np.float32)
     np.add.at(expect, ids[ids < 6], vals[ids < 6])
     np.testing.assert_allclose(out, expect, rtol=1e-6)
@@ -171,8 +175,10 @@ def test_spmv_windowed_matches_oracle():
     from spartan_tpu.parallel import mesh as mesh_mod
 
     rng = np.random.RandomState(4)
-    n = 700
-    mat = sp.random(n, n, density=0.01, random_state=rng, format="coo")
+    n = 2 * 16384 + 700  # three column windows, the last partial
+    k = 20_000
+    mat = sp.coo_matrix((rng.rand(k), (rng.randint(0, n, k),
+                                       rng.randint(0, n, k))), shape=(n, n))
     # the windowed kernel is single-device by design; build on a
     # 1-device mesh so the _can_window() guard passes honestly
     m1 = mesh_mod.build_mesh(jax.devices()[:1])
@@ -183,17 +189,86 @@ def test_spmv_windowed_matches_oracle():
     np.testing.assert_allclose(y, mat.tocsr() @ x, rtol=1e-4, atol=1e-6)
 
 
+def _gather_graph(n_cols, seed):
+    """Sorted rows over 4 output windows with window 1 empty, random
+    columns over ``n_cols``, and one hot column that fills many groups."""
+    rng = np.random.RandomState(seed)
+    rows = rng.randint(0, 4096, 3000)
+    rows = rows[(rows // 1024) != 1]
+    cols = rng.randint(0, n_cols, rows.size)
+    hot_rows = rng.randint(2048, 3072, 1500)
+    rows = np.concatenate([rows, hot_rows])
+    cols = np.concatenate([cols, np.full(hot_rows.size, n_cols - 1)])
+    order = np.argsort(rows, kind="stable")
+    return rows[order].astype(np.int32), cols[order].astype(np.int32)
+
+
+@pytest.mark.parametrize("n_cols", [700, 16384, 2 * 16384 + 1, 50_003])
+def test_windowed_gather_bit_exact(n_cols):
+    """The one-hot MXU gather returns ``data * x[cols]`` bit for bit
+    (interpret mode here), at fewer columns than one window, exactly
+    one, one past two, and a ragged count."""
+    from spartan_tpu.kernels.segment import windowed_gather
+    from spartan_tpu.ops.segment import SegmentPlan
+
+    rows, cols = _gather_graph(n_cols, seed=n_cols)
+    rng = np.random.RandomState(1)
+    # values over many binades, so all three bf16 parts carry bits
+    x = (rng.randn(n_cols) * 10.0 ** rng.uniform(-20, 20, n_cols)
+         ).astype(np.float32)
+    data = rng.rand(rows.size).astype(np.float32)
+    plan = SegmentPlan(rows, 4096, cols=cols, num_cols=n_cols)
+    got = windowed_gather(jnp.asarray(x), plan._lcols, plan._gwin,
+                          jnp.asarray(plan.reorder(data)))
+    want = plan.reorder(data * x[cols])
+    np.testing.assert_array_equal(np.asarray(got).reshape(-1), want)
+
+
+def test_segment_plan_gather_layout():
+    """Each 1024-entry subblock lies in one output window, each
+    128-entry group in one column window, and the stream is a
+    permutation of the valid entries with padding at data 0."""
+    from spartan_tpu.ops.segment import SegmentPlan as SP
+
+    n_cols = 3 * SP.CW + 5
+    rows, cols = _gather_graph(n_cols, seed=7)
+    rows = np.concatenate([[-2], rows, [4096, 5000]]).astype(np.int32)
+    cols = np.concatenate([[0], cols, [1, 2]]).astype(np.int32)
+    plan = SP(rows, 4096, cols=cols, num_cols=n_cols)
+    valid = slice(1, rows.size - 2)
+    e = rows[valid].size
+    assert np.unique(plan.perm).size == e == plan.perm.size
+    assert plan.padded_size % (SP.GB * SP.GR) == 0
+    assert plan.groups * SP.GB == plan.padded_size
+    slot_row = np.full(plan.padded_size, -1)
+    slot_row[plan.perm] = rows[valid]
+    slot_col = np.full(plan.padded_size, -1)
+    slot_col[plan.perm] = cols[valid]
+    wb = np.asarray(plan._wb)
+    filled = slot_row >= 0
+    assert (slot_row[filled] // SP.W
+            == wb[np.flatnonzero(filled) // SP.EB]).all()
+    gwin = np.asarray(plan._gwin).reshape(-1)
+    lcols = np.asarray(plan._lcols).reshape(-1)
+    assert (slot_col[filled] // SP.CW
+            == gwin[np.flatnonzero(filled) // SP.GB]).all()
+    np.testing.assert_array_equal(lcols[filled], slot_col[filled] % SP.CW)
+    # padding slots: data 0, and a column inside the x windows
+    pdata = plan.reorder(np.arange(1, rows.size + 1, dtype=np.float32))
+    np.testing.assert_array_equal(pdata[plan.perm], np.arange(2, e + 2))
+    assert (pdata[~filled] == 0).all()
+    assert (gwin < -(-n_cols // SP.CW)).all()
+    assert ((lcols >= 0) & (lcols < SP.CW)).all()
+    assert (np.asarray(plan._ids2d).reshape(-1)[~filled] == SP.W).all()
+
+
 def test_segment_plan_partial_trailing_block():
     """Regression: num_segments not a multiple of the flush block size
     (131072 elements) must still flush the trailing partial block."""
-    from spartan_tpu.ops.segment import SegmentPlan
-
     n = 140000
     ids = np.array([5, 139999], np.int32)
     vals = np.array([1.5, 2.0], np.float32)
-    plan = SegmentPlan(ids, n)
-    out = np.asarray(jax.device_get(
-        plan.segment_sum(jnp.asarray(plan.reorder(vals)))))
+    out = _plan_segment_sum(ids, n, vals)
     assert out[5] == pytest.approx(1.5)
     assert out[139999] == pytest.approx(2.0)
     assert out.sum() == pytest.approx(3.5)
@@ -203,15 +278,11 @@ def test_segment_plan_skewed_ids_flush_after_accumulate():
     """Regression: heavily skewed ids (all entries in the first output
     block, more entry steps than output blocks) must not lose the
     contributions of late grid steps."""
-    from spartan_tpu.ops.segment import SegmentPlan
-
     n = 256 * 1024
-    e = 24576  # 3 grid steps of entries, all into segment 0
+    e = 24576  # 3 subblock steps of entries, all into segment 0
     ids = np.zeros(e, np.int32)
     vals = np.ones(e, np.float32)
-    plan = SegmentPlan(ids, n)
-    out = np.asarray(jax.device_get(
-        plan.segment_sum(jnp.asarray(plan.reorder(vals)))))
+    out = _plan_segment_sum(ids, n, vals)
     assert out[0] == pytest.approx(e)
     assert out[1:].sum() == pytest.approx(0.0)
 
@@ -219,13 +290,9 @@ def test_segment_plan_skewed_ids_flush_after_accumulate():
 def test_segment_plan_drops_negative_ids():
     """Regression (ADVICE r1): negative ids are dropped like
     jax.ops.segment_sum drops them, not crashed on in bincount."""
-    from spartan_tpu.ops.segment import SegmentPlan
-
     ids = np.array([-3, -1, 0, 2, 2, 5, 9], np.int32)
     vals = np.arange(1, 8, dtype=np.float32)
-    plan = SegmentPlan(ids, 6)  # -3, -1 and 9 out of range
-    out = np.asarray(jax.device_get(
-        plan.segment_sum(jnp.asarray(plan.reorder(vals)))))
+    out = _plan_segment_sum(ids, 6, vals)  # -3, -1 and 9 out of range
     keep = (ids >= 0) & (ids < 6)
     expect = np.zeros(6, np.float32)
     np.add.at(expect, ids[keep], vals[keep])
@@ -238,6 +305,29 @@ def test_spmv_windowed_forced_unavailable_raises(mesh2d):
     a = SparseDistArray.from_dense(np.eye(8, dtype=np.float32))
     with pytest.raises(ValueError, match="windowed"):
         a.spmv(np.ones(8, np.float32), impl="windowed")
+
+
+@pytest.mark.parametrize("shape,ok", [
+    ((2 << 20, 8 << 20), True),        # both bounds: rows 2M, columns 8M
+    ((1024, (8 << 20) + 1), False),    # x's parts past the gather's VMEM
+    (((2 << 20) + 1, 16), False),      # the merge's output past VMEM
+])
+def test_windowed_shape_bounds(shape, ok):
+    """The windowed path's structural bounds on one device: rows by the
+    merge's VMEM-resident output, columns by the gather's VMEM-resident
+    x (``SegmentPlan.MAX_COLS``); past either, the default is BCOO and
+    a forced impl='windowed' fails fast."""
+    from spartan_tpu.parallel import mesh as mesh_mod
+
+    m1 = mesh_mod.build_mesh(jax.devices()[:1])
+    with mesh_mod.use_mesh(m1):
+        a = SparseDistArray.from_coo(np.array([0, 5]), np.array([1, 7]),
+                                     np.ones(2), shape)
+        assert a._can_window() == ok
+        if not ok:
+            assert a.default_impl() == "bcoo"
+            with pytest.raises(ValueError, match="columns"):
+                a.spmv(jnp.ones(shape[1], jnp.float32), impl="windowed")
 
 
 def test_transition_cached_and_clearable():

@@ -123,6 +123,74 @@ def test_windowed_segsum(topo, mosaic, nodes):
     assert "tpu_custom_call" in txt
 
 
+def _gather_plan(edges: int, nodes: int, cols: int = 0):
+    """The gather layout of ``edges`` entries of a ``nodes`` x ``cols``
+    matrix (square by default), worst case padding (every nonempty
+    (output window, column window) block and every output window
+    rounded up): the plan's dims and its group count."""
+    from spartan_tpu.ops.segment import PlanDims
+    from spartan_tpu.ops.segment import SegmentPlan as SP
+
+    static, _ = _windowed_shapes(edges, nodes)
+    n_win = -(-nodes // SP.W)
+    blocks = min(n_win * -(-(cols or nodes) // SP.CW), edges)
+    total = edges + blocks * (SP.GB - 1) + n_win * (SP.EB - 1)
+    step = max(SP.GB * SP.GR, 1 << (total.bit_length() - 7))
+    grand = -(-total // step) * step
+    dims = PlanDims(nodes, static["rows_pad"], grand // (SP.SUB * SP.EB),
+                    static["outblk"], SP.SUB)
+    return dims, grand // SP.GB
+
+
+def _gather_args(mesh, cols: int, groups: int):
+    from spartan_tpu.ops.segment import SegmentPlan as SP
+
+    return (_sds((cols,), F32, mesh), _sds((groups, 128), I32, mesh),
+            _sds((groups // SP.GR, 1, SP.GR), I32, mesh),
+            _sds((groups * 128,), F32, mesh))
+
+
+@pytest.mark.parametrize("nodes,cols", [
+    (1 << 20, 1 << 20), (2 << 20, 2 << 20), (2 << 20, 8 << 20)],
+    ids=["1048576", "2097152", "2097152x8388608"])
+def test_windowed_gather(topo, mosaic, nodes, cols):
+    """config 5's SpMV gather: 33.5M entries (GAP Urand, scale 20) over
+    1M and 2M nodes, and at the windowed path's bounds (2M rows,
+    ``SegmentPlan.MAX_COLS`` columns, whose parts fill 48 of the
+    kernel's 64 MiB of VMEM); x resident in VMEM as three bf16 parts,
+    at the layout's worst-case group count."""
+    from spartan_tpu.kernels.segment import windowed_gather
+    from spartan_tpu.ops.segment import SegmentPlan as SP
+
+    assert cols <= SP.MAX_COLS
+    mesh = _mesh(topo, (1, 1))
+    _, groups = _gather_plan(32 << 20, nodes, cols)
+    txt = _compiled_text(windowed_gather,
+                         *_gather_args(mesh, cols, groups))
+    assert "tpu_custom_call" in txt
+
+
+def test_pagerank_loop(topo, mosaic):
+    """The rank10 cell's program: the fused power iteration, gather
+    and merge kernels inside one fori_loop, at 1M nodes; no XLA gather
+    is left in it."""
+    from spartan_tpu.examples.pagerank import _pagerank_loop
+
+    mesh = _mesh(topo, (1, 1))
+    nodes = 1 << 20
+    dims, groups = _gather_plan(32 << 20, nodes)
+    x, lcols, gwin, pdata = _gather_args(mesh, nodes, groups)
+    bufs = (pdata, lcols, gwin,
+            _sds((groups, 128), I32, mesh),
+            _sds((groups * 128 // 1024,), I32, mesh))
+    txt = _pagerank_loop.lower(bufs, x, _sds((), F32, mesh),
+                               _sds((), I32, mesh), n=nodes,
+                               dims=dims).compile().as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"',
+                          txt)) == 2
+    assert " gather(" not in txt
+
+
 @pytest.mark.parametrize("shape", [(1 << 20, 64), (1 << 20,)])
 def test_segment_sum_block(topo, shape):
     from spartan_tpu.kernels.segment import segment_sum_block
@@ -200,6 +268,11 @@ def _named_kernel_text(topo, kernel: str) -> str:
             windowed_segsum, _sds((grand,), F32, mesh),
             _sds((grand // 128, 128), I32, mesh),
             _sds((grand // 1024,), I32, mesh), **static)
+    if kernel == "windowed_gather":
+        from spartan_tpu.kernels.segment import windowed_gather
+
+        return _compiled_text(windowed_gather,
+                              *_gather_args(mesh, 1 << 14, 256))
     if kernel == "segment_sum_block":
         from spartan_tpu.kernels.segment import segment_sum_block
 
@@ -219,8 +292,8 @@ def _named_kernel_text(topo, kernel: str) -> str:
 
 
 @pytest.mark.parametrize("kernel", ["kmeans_lloyd", "windowed_segsum",
-                                    "segment_sum_block", "bincount_block",
-                                    "conv_block"])
+                                    "windowed_gather", "segment_sum_block",
+                                    "bincount_block", "conv_block"])
 def test_kernel_carries_its_name(topo, mosaic, kernel):
     """The compiled custom call is named by its ``pallas_call``'s
     ``name``, which the device trace's op events carry."""

@@ -207,6 +207,7 @@ def test_other_gc_callbacks_see_every_phase_when_it_unhooks():
 
 def test_pagerank_setup_and_dispatch_spans():
     from spartan_tpu.examples.pagerank import pagerank
+    from spartan_tpu.ops.segment import SegmentPlan
 
     rng = np.random.RandomState(0)
     n = 64
@@ -217,13 +218,19 @@ def test_pagerank_setup_and_dispatch_spans():
     names = [s.name for s in st.trace_events()]
     assert "transition" in names and "fetch" in names
     st.trace_clear()
-    links.transition()._ensure_plan()
+    t = links.transition()
+    t._ensure_plan()
     spans = st.trace_events()
     plan = next(s for s in spans if s.name == "segment_plan")
     inner = [s for s in spans if s.name in ("fetch", "upload")
              and plan.ts <= s.ts <= plan.ts + plan.dur]
     assert [s.name for s in inner].count("fetch") == 3
-    assert [s.name for s in inner].count("upload") == 2
+    # the data in plan order, and the plan's four index buffers
+    assert [s.name for s in inner].count("upload") == 5
+    assert plan.args["entries"] == t.nse
+    grid_step = SegmentPlan.GB * SegmentPlan.GR
+    assert plan.args["padded"] % grid_step == 0
+    assert plan.args["groups"] * SegmentPlan.GB == plan.args["padded"]
 
 
 def test_pagerank_fused_dispatch_then_split_fetch(monkeypatch):
