@@ -260,7 +260,7 @@ def worker_kmeans(iters: int, reps: int) -> None:
     import jax.numpy as jnp
 
     platform = jax.devices()[0].platform
-    from spartan_tpu.ops import kmeans as kk
+    from spartan_tpu.kernels import kmeans as kk
 
     _arm_stage_forensics("kmeans")
     n, d, k = KM_N, KM_D, KM_K
@@ -270,7 +270,7 @@ def worker_kmeans(iters: int, reps: int) -> None:
     block = kk._BLOCK  # pad to the kernel's block so supports() holds
     npad = -(-n // block) * block
     if kk.supports(npad, d, k):
-        # fused Pallas iteration kernel (ops/kmeans.py): one VMEM pass
+        # fused Pallas iteration kernel (kernels/kmeans.py): one VMEM pass
         # per iteration, all iterations in one dispatch
         pts = jnp.concatenate(
             [jnp.asarray(pts_np), jnp.zeros((npad - n, d), jnp.float32)])

@@ -131,20 +131,6 @@ def _from_run_all(doc: Dict[str, Any]) -> Dict[str, float]:
              ("skew_overhead", "skew_worst_imbalance_ratio")),
             ("skew_sampled_plans",
              ("skew_overhead", "skew_sampled_plans")),
-            ("kernels_off_overhead_ratio",
-             ("native_overhead", "kernels_off_overhead_ratio")),
-            ("native_kmeans_speedup",
-             ("native_overhead", "native_kmeans_speedup")),
-            ("native_topk_speedup",
-             ("native_overhead", "native_topk_speedup")),
-            ("native_histogram_speedup",
-             ("native_overhead", "native_histogram_speedup")),
-            ("native_sort_exchange_speedup",
-             ("native_overhead", "native_sort_exchange_speedup")),
-            ("native_stencil_speedup",
-             ("native_overhead", "native_stencil_speedup")),
-            ("native_segment_speedup",
-             ("native_overhead", "native_segment_speedup")),
     ):
         v = get(*path)
         if v is not None:

@@ -71,7 +71,7 @@ def config3_kmeans(st):
 
     from spartan_tpu.examples.kmeans import kmeans_step
     from spartan_tpu.expr.base import ValExpr
-    from spartan_tpu.ops import kmeans as kmeans_kernel
+    from spartan_tpu.kernels import kmeans as kmeans_kernel
 
     n = 10_000 if SMALL else 1_000_000
     d, k = 128, 64
@@ -290,23 +290,6 @@ def profile_overhead(st):
     return po.measure(iters=60, n=512 if SMALL else 4096)
 
 
-def native_overhead(st):
-    """Pallas kernel layer gates (benchmarks/native_vs_gspmd.py): the
-    layer's off-path toll on the steady-state hit path (<=1% is the
-    ISSUE-12 gate; policy_key folds into the memoized flags key, so
-    the hit path has no kernel-layer code at all) plus the per-op
-    native-vs-GSPMD ABBA A/B — interpret-mode parity evidence on CPU
-    (reported unjudged), TPU speedup floors committed in
-    thresholds.json gate the next TPU run (the measured-win
-    contract)."""
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import native_vs_gspmd as nv
-
-    if SMALL:
-        return nv.measure(iters=40, n=1024, reps=2)
-    return nv.measure(iters=60, n=4096, reps=3)
-
-
 def incremental_overhead(st):
     """Delta-aware evaluation gates (benchmarks/incremental.py): the
     engine's off-path toll on the steady-state hit path with
@@ -495,9 +478,6 @@ def guard_metrics(report) -> dict:
         "profile_off_overhead_ratio":
             report["profile_overhead"].get(
                 "profile_off_overhead_ratio"),
-        "kernels_off_overhead_ratio":
-            report["native_overhead"].get(
-                "kernels_off_overhead_ratio"),
         "incremental_off_overhead_ratio":
             report["incremental_overhead"].get(
                 "incremental_off_overhead_ratio"),
@@ -518,22 +498,6 @@ def guard_metrics(report) -> dict:
                      "audit_sort_comm_kib",
                      "audit_splice_full_gather_findings",
                      "audit_splice_comm_kib")},
-        # per-op pallas-vs-gspmd floors: judged on TPU only (the CPU
-        # native arm is interpret-mode parity evidence — no cpu
-        # thresholds are committed for these)
-        "native_kmeans_speedup":
-            report["native_overhead"].get("native_kmeans_speedup"),
-        "native_topk_speedup":
-            report["native_overhead"].get("native_topk_speedup"),
-        "native_histogram_speedup":
-            report["native_overhead"].get("native_histogram_speedup"),
-        "native_sort_exchange_speedup":
-            report["native_overhead"].get(
-                "native_sort_exchange_speedup"),
-        "native_stencil_speedup":
-            report["native_overhead"].get("native_stencil_speedup"),
-        "native_segment_speedup":
-            report["native_overhead"].get("native_segment_speedup"),
     }
 
 
@@ -568,7 +532,6 @@ def main():
         "redistribution_overhead": _with_metrics(
             redistribution_overhead, st),
         "profile_overhead": _with_metrics(profile_overhead, st),
-        "native_overhead": _with_metrics(native_overhead, st),
         "incremental_overhead": _with_metrics(incremental_overhead,
                                               st),
         "plan_audit_overhead": _with_metrics(plan_audit_overhead, st),
@@ -613,7 +576,6 @@ def main():
                  "calibration_off_overhead_ratio": 0.01,
                  "redist_off_overhead_ratio": 0.01,
                  "profile_off_overhead_ratio": 0.01,
-                 "kernels_off_overhead_ratio": 0.01,
                  "incremental_off_overhead_ratio": 0.01,
                  "audit_off_overhead_ratio": 0.01}
         # golden-audit gates: collective COUNTS commit exact
@@ -631,24 +593,10 @@ def main():
                          "audit_sort_comm_kib",
                          "audit_splice_comm_kib"}
         # fixed FLOORS (ISSUE gates on ratios that must stay high):
-        # coalescing must amortize dispatch >=3x across 16 clients;
-        # a Pallas kernel keeps its slot only while it beats (kmeans)
-        # or at least matches (the rest) the GSPMD lowering on TPU —
-        # segment carries NO floor (its Pallas form already measured
-        # worse on v5e; kept as ablation, auto never selects it)
+        # coalescing must amortize dispatch >=3x across 16 clients
         fixed_min = {"incremental_warm_speedup_1pct": 5.0,
-                     "serve_coalesced_speedup": 3.0,
-                     "native_kmeans_speedup": 1.0,
-                     "native_topk_speedup": 0.95,
-                     "native_histogram_speedup": 0.95,
-                     "native_sort_exchange_speedup": 0.95,
-                     "native_stencil_speedup": 0.95}
+                     "serve_coalesced_speedup": 3.0}
         for k, v in metrics.items():
-            if k.startswith("native_") and (k not in fixed_min
-                                            or platform != "tpu"):
-                # per-op pallas floors are TPU-only commitments, and
-                # native_segment_speedup is report-only everywhere
-                continue
             if (k == "incremental_warm_speedup_1pct"
                     and platform != "cpu"):
                 # the >=5x warm-step gate is the ISSUE-16 CPU

@@ -6,10 +6,10 @@ kernel; config 5 needs sparse PageRank / SSVD). TPU-first design per
 SURVEY.md §7 hard part 2: *static* nse (padded), entries lexicographically
 (row, col)-sorted with duplicates summed at construction (COO semantics),
 stored as three device arrays (data, rows, cols) sharded along the entry
-axis. SpMV is ``segment_sum(data * x[cols], rows)`` — the scatter-merge
-runs through :mod:`spartan_tpu.ops.segment` (the Pallas/XLA merge
-kernels); on one chip the windowed path runs the gather as a Pallas
-kernel too (``ops.segment.windowed_spmv``), and a BCOO bridge exposes
+axis. SpMV is ``segment_sum(data * x[cols], rows)``: on one chip the
+windowed path runs the gather and the merge as Pallas kernels
+(``ops.segment.windowed_spmv``), on a mesh each entry shard merges with
+XLA's scatter and one psum, and a BCOO bridge exposes
 ``jax.experimental.sparse`` fast paths. Padding entries carry
 ``row = nrows`` so every merge drops them (XLA segment semantics).
 """
@@ -40,21 +40,14 @@ def _todense_kernel(data, rows, cols, *, n, m):
     return flat.reshape(n, m)
 
 
-def _contrib_segsum(data, rows, cols, x, n, impl=None):
+def _contrib_segsum(data, rows, cols, x, n):
     """Shared SpMV body: gather operand rows, scale by entry values,
     segment-merge into output rows (out-of-range padding rows drop)."""
     gathered = x[cols]
     contrib = data * gathered if gathered.ndim == 1 \
         else data[:, None] * gathered
-    if impl is not None:
-        return segment_sum(contrib, rows, n, impl=impl, sorted_ids=True)
     return jax.ops.segment_sum(contrib, rows, num_segments=n,
                                indices_are_sorted=True)
-
-
-@functools.partial(jax.jit, static_argnames=("n", "impl"))
-def _spmv_kernel(data, rows, cols, x, *, n, impl):
-    return _contrib_segsum(data, rows, cols, x, n, impl=impl)
 
 
 @functools.partial(jax.jit, static_argnames=("shape",))
@@ -413,9 +406,9 @@ class SparseDistArray:
                 and mesh_mod.device_count(self.mesh) == 1)
 
     def _default_windowed(self) -> bool:
-        from ..ops.segment import _pallas_available
+        from ..kernels.registry import interpret_mode
 
-        return self._can_window() and _pallas_available()
+        return self._can_window() and not interpret_mode()
 
     def default_impl(self, x_ndim: int = 1) -> str:
         """The spmv path the default dispatch selects for an operand of
@@ -442,8 +435,8 @@ class SparseDistArray:
         Default: the windowed Pallas path on a single TPU (vector x);
         on a multi-device mesh the explicit entry-sharded
         segment-sum + psum path ('sharded'); else BCOO matvec.
-        ``impl`` forces a path ('windowed' | 'sharded' | 'bcoo' |
-        'xla' | 'onehot' | 'pallas' segment-merge ablations)."""
+        ``impl`` forces one of those paths ('windowed' | 'sharded' |
+        'bcoo')."""
         x = x.jax_array if isinstance(x, DistArray) else jnp.asarray(x)
         if impl is None:
             impl = self.default_impl(x.ndim)
@@ -454,7 +447,7 @@ class SparseDistArray:
             if x.ndim != 1:
                 raise ValueError(
                     "impl='windowed' supports vector x only; use the "
-                    "'bcoo' or 'xla' path for (n, d) operands")
+                    "'bcoo' or 'sharded' path for (n, d) operands")
             if not self._can_window():
                 # fail fast instead of silently gathering a sharded /
                 # oversized matrix to host for the single-device kernel
@@ -469,8 +462,8 @@ class SparseDistArray:
         if impl == "bcoo":
             return _spmv_bcoo_kernel(self.data, self.rows, self.cols, x,
                                      shape=self.shape)
-        return _spmv_kernel(self.data, self.rows, self.cols, x,
-                            n=self.shape[0], impl=impl)
+        raise ValueError(f"unknown spmv impl {impl!r}; expected "
+                         "'windowed', 'sharded' or 'bcoo'")
 
     def rsums(self) -> jax.Array:
         """Row sums (out-degree weights for PageRank)."""

@@ -71,7 +71,7 @@ def assign_points(points: Expr, centers: Expr) -> Expr:
 def _kernel_pad(n: int) -> int:
     """Pad rows so every mesh row shard holds whole 1024-point blocks
     (the kernel is per-shard now — docs/KERNELS.md)."""
-    from ..ops import kmeans as kmeans_kernel
+    from ..kernels import kmeans as kmeans_kernel
     from ..parallel import mesh as mesh_mod
 
     p = max(int(mesh_mod.get_mesh().shape.get(
@@ -81,7 +81,7 @@ def _kernel_pad(n: int) -> int:
 
 
 def _kernel_supports(n: int, d: int, k: int) -> bool:
-    from ..ops import kmeans as kmeans_kernel
+    from ..kernels import kmeans as kmeans_kernel
 
     return kmeans_kernel.supports(_kernel_pad(n), d, k)
 
@@ -110,7 +110,7 @@ def kmeans(points, k: int, num_iter: int = 10,
         # fused Pallas iteration kernel: distances + argmin + one-hot
         # accumulate stream through VMEM once per iteration; 4 ms/iter
         # at 1M x 128, k=64 on v5e vs 18.6 ms for the XLA-fused loop
-        from ..ops import kmeans as kmeans_kernel
+        from ..kernels import kmeans as kmeans_kernel
 
         pts = points.evaluate().jax_array
         npad = _kernel_pad(n)

@@ -29,7 +29,6 @@ from ..array import distarray as da
 from ..array import tiling as tiling_mod
 from ..array.distarray import DistArray
 from ..array.tiling import Tiling
-from ..kernels import registry as kernels_mod
 from ..obs import ledger as ledger_mod
 from ..obs import monitor as monitor_mod
 from ..obs import numerics as numerics_mod
@@ -1099,11 +1098,6 @@ def _opt_flags_key() -> Tuple:
         # the redistribution planner changes BOTH the DP's edge costs
         # and the emitted lowering (explicit schedules vs GSPMD), so
         # planned and implicit plans must never alias
-        # the kernel-backend policy (spartan_tpu/kernels) changes the
-        # lowering of the irregular ops (Pallas vs GSPMD) for the same
-        # structural signature, so native and fallback plans are keyed
-        # apart the same way (platform is process-constant; flag
-        # writes bump the memo version)
         # carry sharding (expr/loop FLAGS.shard_loop_carries) changes
         # the loop program's layout constraints: sharded-carry and
         # replicated-carry plans must never alias (the chosen layouts
@@ -1115,8 +1109,7 @@ def _opt_flags_key() -> Tuple:
                FLAGS.tiling_memory_weight,
                bool(FLAGS.audit_numerics), cal,
                bool(FLAGS.redistribution_planner),
-               bool(getattr(FLAGS, "shard_loop_carries", False)),
-               kernels_mod.policy_key())
+               bool(getattr(FLAGS, "shard_loop_carries", False)))
         _opt_key_memo = (ver, key)
     return key + (getattr(degrade_mod._TLS, "rung", None),)
 
@@ -1579,13 +1572,9 @@ def _build_plan(expr: Expr, mesh, rctx: Optional[_PlanSigCtx],
     # flag is keyed like audit: a planner-on trace emits explicit
     # collective schedules where the planner-off trace emits
     # with_sharding_constraint, for the same structural signature.
-    # the kernel-backend policy is keyed like audit/planner: a
-    # Pallas-lowered executable must never alias the GSPMD executable
-    # of the same structure (or an interpret-mode one a Mosaic one)
     key = (root_sig, tuple(t.axes for t in out_tilings),
            (mesh_mod._EPOCH,) + tuple(sorted(mesh.shape.items())),
-           audit, degrade_rung, redistribute_mod.planner_on(),
-           kernels_mod.policy_key())
+           audit, degrade_rung, redistribute_mod.planner_on())
 
     leaf_ids = tuple(l._id for l in leaves)
     out_shardings = tuple(t.sharding(mesh) for t in out_tilings)

@@ -261,13 +261,9 @@ def triu(x, k=0) -> Expr:
 
 class BincountExpr(Expr):
     """Counts of ints in ``[0, length)`` — the histogram family's
-    reduction. Lowers through the kernel layer (docs/KERNELS.md): when
-    ``kernels.select`` picks Pallas, each row shard counts its entries
-    with the blocked one-hot kernel (spartan_tpu/kernels/histogram.py)
-    and the count rows merge with one psum; otherwise the traced
-    ``jnp.bincount`` (XLA scatter-add, GSPMD-partitioned). Negative
-    ids clip to bucket 0 and ids >= length are dropped on both
-    backends (jnp.bincount parity)."""
+    reduction, lowered as the traced ``jnp.bincount`` (XLA scatter-add,
+    GSPMD-partitioned). Negative ids clip to bucket 0 and ids >= length
+    are dropped (jnp.bincount semantics)."""
 
     def __init__(self, x: Expr, length: int):
         self.x = x
@@ -281,14 +277,7 @@ class BincountExpr(Expr):
         return BincountExpr(new_children[0], self.length)
 
     def _lower(self, env) -> Any:
-        from ..kernels import registry as kernels_mod
-
         v = self.x.lower(env)
-        sel = kernels_mod.node_selection(self)
-        if sel is not None and sel.pallas:
-            from ..kernels import histogram as khist
-
-            return khist.bincount_sharded(v, self.length, sel)
         return jnp.bincount(v.ravel(), length=self.length)
 
     def _sig(self, ctx):

@@ -1,25 +1,11 @@
-"""Partitionable Pallas kernel layer (docs/KERNELS.md).
+"""Pallas kernel layer (docs/KERNELS.md).
 
-shard_map-wrapped Pallas TPU kernels for the ops GSPMD lowers poorly —
-segment reductions, histogram/bincount, distributed top-k, the sample
-sort's partition exchange, halo stencils, the fused k-means pass —
-with every kernel's grid/block schedule derived from the Tiling the
-planner already committed (registry.derive), selected per
-op/shape/platform by :func:`registry.select`, and keyed into the
-plan/compile caches via :func:`registry.policy_key` so native and
-fallback executables never alias.
+The three kernels that won their slot on the chip, each with the cell
+that shows it: the fused k-means pass (``kmeans.py``, shard_map-wrapped
+over the row tiling) and the windowed SpMV gather and segment-sum
+(``segment.py``). ``registry.select`` runs Pallas on TPU only;
+``registry.derive`` gives a kernel its grid/block schedule from the
+committed Tiling.
 
 Pallas imports live ONLY under this package (lint rule 12).
 """
-
-from __future__ import annotations
-
-from . import registry
-from .registry import (Schedule, Selection, derive, interpret_mode,
-                       mode, node_selection, plan_entries, policy_key,
-                       select)
-
-__all__ = [
-    "registry", "Schedule", "Selection", "derive", "interpret_mode",
-    "mode", "node_selection", "plan_entries", "policy_key", "select",
-]

@@ -1,25 +1,10 @@
-"""Segment-sum kernels: blocked one-hot accumulation on the MXU.
+"""Windowed segment kernels for SpMV on one chip (ops/segment.py's
+SegmentPlan lays out their host-planned stream):
 
-Owns every Pallas call the segment/scatter-add family uses (lint rule
-12). Three kernels:
-
-* :func:`segment_sum_block` — the per-shard blocked one-hot kernel
-  (promoted from the seed's single-device ``ops/segment.py``
-  ``_segment_sum_pallas``): the entry stream is tiled over a
-  sequential grid, each tile builds its one-hot block in VMEM and
-  accumulates ``block.T @ vals`` into the output block.
-* :func:`windowed_segsum` — SegmentPlan's windowed sorted-segment
-  kernel (moved verbatim from ops/segment.py; host-planned layout).
+* :func:`windowed_segsum` — the windowed sorted-segment merge.
 * :func:`windowed_gather` — its mirror image for an SpMV's ``x[cols]``:
   ``x`` resident in VMEM, read per 128-entry group through one-hot
   products on the MXU over the group's column window.
-
-:func:`segment_sum_sharded` is the partitionable form: the operand is
-row-sharded over the mesh row axis, every shard runs
-:func:`segment_sum_block` on its local entries under ``shard_map``,
-and the per-shard ``(k, d)`` partials merge with ``psum_scatter``
-(k divisible by the shard count — each chip keeps its k/p output
-rows) or a plain ``psum`` otherwise.
 """
 
 from __future__ import annotations
@@ -27,126 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..array import tiling as tiling_mod
-from ..parallel import mesh as mesh_mod
-from ..parallel import redistribute as redist_mod
 from . import registry
-
-
-def segment_sum_block(vals: jax.Array, ids: jax.Array,
-                      num_segments: int, block_e: int = 512,
-                      interpret: bool = False) -> jax.Array:
-    """Blocked one-hot accumulation over ONE shard's entry stream.
-
-    Grid over entry blocks (sequential on TPU); the output block is
-    revisited every step and accumulated in VMEM. ``num_segments`` and
-    the feature dim are padded to lane/sublane multiples; ids outside
-    ``[0, num_segments)`` are dropped (XLA segment_sum semantics)."""
-    from jax.experimental import pallas as pl
-
-    squeeze = vals.ndim == 1
-    if squeeze:
-        vals = vals[:, None]
-    e, d = vals.shape
-    k = num_segments
-    # pad to TPU tiling: entries to block_e, segments/features to 128/8
-    e_pad = -e % block_e
-    if e_pad:
-        vals = jnp.pad(vals, ((0, e_pad), (0, 0)))
-        ids = jnp.pad(ids, (0, e_pad), constant_values=k)  # out of range
-    k_pad = -k % 8
-    d_pad = -d % 128
-    vals = jnp.pad(vals, ((0, 0), (0, d_pad)))
-    n_blocks = vals.shape[0] // block_e
-    k_total = k + k_pad
-    # ids as (n_blocks, block_e): 2-D blocks match the XLA layout Mosaic
-    # expects (1-D s32 operands hit a T(1024)/T(512) tiling mismatch)
-    ids2d = ids.astype(jnp.int32).reshape(n_blocks, block_e)
-
-    def kernel(ids_ref, vals_ref, out_ref):
-        step = pl.program_id(0)
-
-        @pl.when(step == 0)
-        def _init():
-            out_ref[:] = jnp.zeros_like(out_ref)
-
-        seg = jax.lax.broadcasted_iota(jnp.int32, (block_e, k_total), 1)
-        onehot = (ids_ref[step, :][:, None] == seg).astype(vals_ref.dtype)
-        out_ref[:] += jnp.dot(onehot.T, vals_ref[:],
-                              preferred_element_type=out_ref.dtype,
-                              precision="highest")
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            # whole ids table resident (Mosaic requires sublane-divisible
-            # or full blocks); the kernel row-indexes it by step
-            pl.BlockSpec((n_blocks, block_e), lambda i: (0, 0)),
-            pl.BlockSpec((block_e, vals.shape[1]), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((k_total, vals.shape[1]), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((k_total, vals.shape[1]),
-                                       vals.dtype),
-        interpret=interpret,
-        name="segment_sum_block",
-    )(ids2d, vals)
-    out = out[:k, :d]
-    return out[:, 0] if squeeze else out
-
-
-def segment_sum_sharded(vals: jax.Array, ids: jax.Array,
-                        num_segments: int,
-                        sel: registry.Selection,
-                        mesh=None, block_e: int = 512) -> jax.Array:
-    """The partitionable segment sum: row-shard the entry stream, run
-    :func:`segment_sum_block` per shard, merge partials with
-    ``psum_scatter`` (when the shard count divides ``num_segments``)
-    or ``psum``. Output is replicated either way — the scatter merge
-    finishes with an all-gather of the k/p slices, which together cost
-    one all-reduce's bytes (the rs+ag decomposition)."""
-    from jax import shard_map
-
-    mesh = mesh or mesh_mod.get_mesh()
-    axis = tiling_mod.AXIS_ROW
-    p = int(mesh.shape.get(axis, 1))
-    interpret = sel.interpret
-    if p <= 1:
-        return segment_sum_block(vals, ids, num_segments, block_e,
-                                 interpret=interpret)
-    squeeze = vals.ndim == 1
-    if squeeze:
-        vals = vals[:, None]
-    e, d = vals.shape
-    e_pad = -e % p
-    if e_pad:
-        vals = jnp.pad(vals, ((0, e_pad), (0, 0)))
-        ids = jnp.pad(ids, (0, e_pad), constant_values=num_segments)
-    ids = ids.astype(jnp.int32)
-    t_vals = tiling_mod.row(2)
-    t_ids = tiling_mod.row(1)
-    vals = redist_mod.constrain(vals, t_vals, mesh)
-    ids = redist_mod.constrain(ids, t_ids, mesh)
-    scatter = num_segments % p == 0
-
-    def shard_fn(v, i):
-        part = segment_sum_block(v, i, num_segments, block_e,
-                                 interpret=interpret)
-        if scatter:
-            # psum-scatter merge: each chip reduces and keeps its own
-            # k/p output rows, then the gather makes it whole — same
-            # wire bytes as one all-reduce, half of psum+broadcast
-            part = jax.lax.psum_scatter(part, axis, tiled=True)
-            return jax.lax.all_gather(part, axis, tiled=True)
-        return jax.lax.psum(part, axis)
-
-    mapped = shard_map(
-        shard_fn, mesh=mesh,
-        in_specs=(t_vals.spec(), t_ids.spec()),
-        out_specs=tiling_mod.replicated(2).spec(),
-        check_vma=False)
-    out = mapped(vals, ids)
-    return out[:, 0] if squeeze else out
 
 
 def windowed_segsum(vals: jax.Array, ids2d: jax.Array, wb: jax.Array,

@@ -228,18 +228,6 @@ def build_plan_report(expr: Any, dag: Any, leaves: Sequence[Any],
     except Exception:  # noqa: BLE001 - the prediction is advisory
         pass
 
-    # kernel-backend decisions (spartan_tpu/kernels): the SAME pure
-    # select() the lowering seam will call per kernel-eligible node —
-    # backend, derived grid/block, and the fallback reason when GSPMD
-    # keeps the slot (docs/KERNELS.md)
-    kernel_nodes = None
-    try:
-        from ..kernels import registry as kernels_mod
-
-        kernel_nodes = kernels_mod.plan_entries(dag) or None
-    except Exception:  # noqa: BLE001 - the report is advisory
-        pass
-
     # planned cross-mesh migrations (elastic re-tiling): leaves that
     # were rehomed or restored through the redistribution planner
     # carry a _migration record — schedule, route, modeled wire
@@ -264,7 +252,6 @@ def build_plan_report(expr: Any, dag: Any, leaves: Sequence[Any],
         "plan_key": key_hash(plan_key),
         "dp_cost": dp_cost,
         "cost_components": components,
-        "kernels": kernel_nodes,
         # the mesh generation this plan was built for: after an
         # elastic rebuild (device loss), post-recovery explains show
         # which epoch — and therefore which device set — a plan binds
@@ -405,22 +392,6 @@ class ExplainReport:
                               f"axis={cstrat['strategy']})")
                 lines.append(f"    {t['node']:<22} {str(t['shape']):<16} "
                              f"{str(t['tiling']):<14}{extra}")
-        if d.get("kernels"):
-            # kernel-lowered nodes: backend=pallas|gspmd + the grid
-            # the tiling derived (docs/KERNELS.md); fallbacks carry
-            # their reason so the A/B is readable from one explain
-            lines.append("  kernel nodes:")
-            for kn in d["kernels"]:
-                line = (f"    {kn['node']:<22} {kn['op']:<14} "
-                        f"backend={kn['backend']}")
-                if kn.get("grid") is not None:
-                    line += (f" grid={tuple(kn['grid'])} "
-                             f"block={tuple(kn['block'])}")
-                if kn.get("interpret"):
-                    line += " [interpret]"
-                if kn.get("reason"):
-                    line += f" ({kn['reason']})"
-                lines.append(line)
         pz = d.get("persist")
         if pz:
             # warm-start provenance (spartan_tpu/persist): whether the

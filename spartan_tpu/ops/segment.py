@@ -1,111 +1,42 @@
-"""Segment-sum / scatter-add merge kernels.
+"""Segment-sum / scatter-add merges.
 
 The TPU-native equivalent of the reference's Cython sparse-merge kernel
-(SURVEY.md §2.5: ``spartan/sparse_update.pyx`` -> "Pallas TPU kernel ...
-for scatter-add / segment-sum merges"). Three paths:
+(SURVEY.md §2.5: ``spartan/sparse_update.pyx``). Two forms:
 
-* ``xla`` — ``jax.ops.segment_sum`` (XLA scatter; always correct).
-* ``onehot`` — one-hot matmul: ``onehot(ids).T @ vals``. Turns the
-  scatter into an MXU matmul — the TPU-first trick for small segment
-  counts (k-means' k=64 centers, histogram merges).
-* ``pallas`` — the kernel layer's blocked one-hot accumulation kernel
-  (spartan_tpu/kernels/segment.py), shard_map-wrapped over the mesh
-  row axis with a psum-scatter merge on multi-device meshes.
-
-Backend selection is the kernel layer's policy (``kernels.select``,
-docs/KERNELS.md), not a per-call platform probe: ``auto`` keeps XLA's
-native scatter (it measured FASTER than the one-hot kernels on v5e —
-1M x 128, k=64: xla 33ms, onehot 67ms, pallas 71ms), and the Pallas
-path stays selectable explicitly (``impl="pallas"`` /
-``FLAGS.segment_impl``) or via ``FLAGS.native_kernels=on`` — the CPU
-CI parity mode that runs it in interpret mode.
+* :func:`segment_sum` — ``jax.ops.segment_sum`` (XLA scatter). It
+  measured faster than a blocked one-hot Pallas kernel and a one-hot
+  matmul on v5e (1M x 128, k=64: xla 33ms, onehot 67ms, pallas 71ms).
+* :class:`SegmentPlan` — a host-planned layout for the windowed
+  sorted-segment and gather kernels (spartan_tpu/kernels/segment.py),
+  the SpMV path PageRank runs on one chip.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..array import tiling as tiling_mod
-from ..kernels import registry as kernels_mod
 from ..kernels.segment import CW as _GATHER_WINDOW
 from ..kernels.segment import GATHER_MAX_COLS as _GATHER_MAX_COLS
-from ..utils.config import FLAGS
-
-FLAGS.define_str("segment_impl", "auto",
-                 "segment-sum path: auto|xla|onehot|pallas")
-
-# one-hot is profitable only when num_segments is small
-_ONEHOT_MAX_SEGMENTS = 4096
-
-
-def _segment_sum_xla(vals: jax.Array, ids: jax.Array,
-                     num_segments: int, sorted_ids: bool = False
-                     ) -> jax.Array:
-    return jax.ops.segment_sum(vals, ids, num_segments=num_segments,
-                               indices_are_sorted=sorted_ids)
-
-
-def _segment_sum_onehot(vals: jax.Array, ids: jax.Array,
-                        num_segments: int) -> jax.Array:
-    onehot = (ids[:, None] == jnp.arange(num_segments)[None, :])
-    onehot = onehot.astype(vals.dtype)
-    # 'highest' so the MXU doesn't round the merge through bf16
-    return jnp.matmul(onehot.T, vals, precision="highest")
-
-
-def _pallas_available() -> bool:
-    """Back-compat probe (array/sparse.py): is the NATIVE Mosaic path
-    available here? The selection policy proper is kernels.select."""
-    return not kernels_mod.interpret_mode()
-
-
-def _select(vals: jax.Array, num_segments: int,
-            force: bool = False) -> kernels_mod.Selection:
-    return kernels_mod.select(
-        "segment_sum", vals.shape, vals.dtype,
-        tiling_mod.row(max(vals.ndim, 1)), force=force,
-        num_segments=num_segments)
 
 
 def segment_sum(vals: jax.Array, ids: jax.Array, num_segments: int,
-                impl: Optional[str] = None,
                 sorted_ids: bool = False) -> jax.Array:
     """Sum ``vals`` rows into ``num_segments`` buckets by ``ids``.
 
     ids outside [0, num_segments) are dropped (XLA segment_sum
     semantics), which the padding paths rely on. ``sorted_ids`` unlocks
     XLA's sorted-scatter fast path (the SparseDistArray invariant)."""
-    from ..kernels import segment as ksegment
-
-    impl = impl or FLAGS.segment_impl
-    forced = impl == "pallas"
-    if impl == "auto":
-        # the kernel-layer policy: XLA's native scatter measured
-        # faster than both matmul paths on v5e (module docstring), so
-        # auto selects pallas only under FLAGS.native_kernels=on (the
-        # parity/ablation mode)
-        sel = _select(vals, num_segments)
-        impl = "pallas" if sel.pallas else "xla"
-    if impl == "pallas":
-        sel = _select(vals, num_segments, force=forced)
-        if not sel.pallas:
-            impl = "onehot"  # constraint fallback (reason: sel.reason)
-        else:
-            return ksegment.segment_sum_sharded(vals, ids,
-                                                num_segments, sel)
-    if impl == "onehot":
-        return _segment_sum_onehot(vals, ids, num_segments)
-    return _segment_sum_xla(vals, ids, num_segments, sorted_ids)
+    return jax.ops.segment_sum(vals, ids, num_segments=num_segments,
+                               indices_are_sorted=sorted_ids)
 
 
 def segment_count(ids: jax.Array, num_segments: int,
-                  dtype=jnp.float32, impl: Optional[str] = None
-                  ) -> jax.Array:
-    return segment_sum(jnp.ones(ids.shape, dtype), ids, num_segments, impl)
+                  dtype=jnp.float32) -> jax.Array:
+    return segment_sum(jnp.ones(ids.shape, dtype), ids, num_segments)
 
 
 def _upload(arr: np.ndarray) -> jax.Array:

@@ -52,7 +52,7 @@ _SAMPLES = 64  # per-shard splitter samples (capped at shard size)
 
 
 def _kernel(xs: jax.Array, axis, p: int, s: int, n: int,
-            with_indices: bool = False, pack_sel=None):
+            with_indices: bool = False):
     """One shard's sample sort over its ``m``-slot row of the padded
     array; ``n`` is the true (unpadded) global length, so slots with
     global index >= n form the validity channel. With ``with_indices``
@@ -99,42 +99,22 @@ def _kernel(xs: jax.Array, axis, p: int, s: int, n: int,
     dst = jnp.where(inv_s == 1, p, dst)     # padding: routed nowhere
     counts = jnp.bincount(dst, length=p + 1)[:p]
     starts = (jnp.cumsum(counts) - counts).astype(jnp.int32)
-    if pack_sel is not None:
-        # kernel-layer pack (spartan_tpu/kernels/exchange.py): bucket
-        # runs are contiguous in the sorted stream, so the send buffer
-        # is a batch of dynamic slices — the Pallas kernel replaces
-        # the XLA scatter this branch used to lower through. Validity
-        # is an iota compare: row j holds counts[j] leading slots.
-        from ..kernels import exchange as kexchange
-
-        send = kexchange.partition_pack(xs_sorted, starts, counts, p,
-                                        pack_sel)
-        vals = exchange(send).ravel()
-        valid_send = (jnp.arange(m, dtype=jnp.int32)[None, :]
-                      < counts[:, None]).astype(jnp.int32)
-        rvalid = exchange(valid_send)
-        valid_key = (1 - rvalid).ravel()
-        k = jnp.sum(rvalid)
-        ridx = (exchange(kexchange.partition_pack(
-            src_idx, starts, counts, p, pack_sel)).ravel()
+    pos = jnp.arange(m, dtype=jnp.int32) - starts[
+        jnp.minimum(dst, p - 1)]
+    ok = (dst < p)
+    posc = jnp.where(ok, pos, m)  # padding scatters out of range
+    vals = exchange(jnp.zeros((p, m), dt)
+                    .at[jnp.minimum(dst, p - 1), posc]
+                    .set(xs_sorted, mode="drop")).ravel()
+    rvalid = exchange(jnp.zeros((p, m), jnp.int32)
+                      .at[jnp.minimum(dst, p - 1), posc]
+                      .set(1, mode="drop"))
+    valid_key = (1 - rvalid).ravel()
+    k = jnp.sum(rvalid)
+    ridx = (exchange(jnp.zeros((p, m), jnp.int32)
+                     .at[jnp.minimum(dst, p - 1), posc]
+                     .set(src_idx, mode="drop")).ravel()
             if with_indices else None)
-    else:
-        pos = jnp.arange(m, dtype=jnp.int32) - starts[
-            jnp.minimum(dst, p - 1)]
-        ok = (dst < p)
-        posc = jnp.where(ok, pos, m)  # padding scatters out of range
-        vals = exchange(jnp.zeros((p, m), dt)
-                        .at[jnp.minimum(dst, p - 1), posc]
-                        .set(xs_sorted, mode="drop")).ravel()
-        rvalid = exchange(jnp.zeros((p, m), jnp.int32)
-                          .at[jnp.minimum(dst, p - 1), posc]
-                          .set(1, mode="drop"))
-        valid_key = (1 - rvalid).ravel()
-        k = jnp.sum(rvalid)
-        ridx = (exchange(jnp.zeros((p, m), jnp.int32)
-                         .at[jnp.minimum(dst, p - 1), posc]
-                         .set(src_idx, mode="drop")).ravel()
-                if with_indices else None)
 
     # -- local merge: (invalid, value) two-key sort keeps padding last
     # even when the data itself contains +inf; indices ride as payload -
@@ -239,18 +219,9 @@ def _run(x: jax.Array, mesh, with_indices: bool,
     t = tiling_mod.Tiling(batch + (name,))
     xp = redist_mod.constrain(xp, t, mesh)
     s = min(_SAMPLES, m)
-    # the kernel layer may pack the send buffer with the Pallas
-    # dynamic-slice kernel instead of XLA scatter (batched sorts vmap
-    # it — pallas_call carries the batch as an extra grid dim)
-    from ..kernels import registry as kernels_mod
-
-    sel = kernels_mod.select("sort_exchange", (n,), x.dtype, t, mesh,
-                             p=p, m=m)
-    pack_sel = sel if sel.pallas else None
 
     def row_fn(r):
-        out = _kernel(r, name, p, s, n, with_indices=with_indices,
-                      pack_sel=pack_sel)
+        out = _kernel(r, name, p, s, n, with_indices=with_indices)
         return out[1] if with_indices else out
 
     def block_fn(v):  # local block: batch axes (locally) whole
@@ -259,12 +230,8 @@ def _run(x: jax.Array, mesh, with_indices: bool,
         rows = v.reshape((-1, m))
         return jax.vmap(row_fn)(rows).reshape(v.shape[:-1] + (m,))
 
-    # the replication checker has no rule for pallas_call; only the
-    # kernel-packed variant relaxes it, so the GSPMD lowering stays
-    # byte-identical with the kernel layer off
-    kw = {"check_vma": False} if pack_sel is not None else {}
     mapped = shard_map(block_fn, mesh=mesh,
-                       in_specs=(t.spec(),), out_specs=t.spec(), **kw)
+                       in_specs=(t.spec(),), out_specs=t.spec())
     out = mapped(xp)
     return out[..., :n] if m * p != n else out
 
@@ -339,15 +306,6 @@ def distributed_topk(x: jax.Array, k: int, largest: bool = True,
     row = tiling_mod.row(1)
     xp = redist_mod.constrain(xp, row, mesh)
     sentinel = _extreme(x.dtype, lo=largest)
-    # kernel-layer per-shard selection (spartan_tpu/kernels/topk.py):
-    # replaces the local lax.top_k (a full sort on TPU) with the
-    # streaming extraction kernel; the candidate gather + final merge
-    # stay identical, so the sentinel/tie-break invariant below holds
-    # for both backends (the kernel ties toward the LOWER index too)
-    from ..kernels import registry as kernels_mod
-
-    topk_sel = kernels_mod.select("topk", (n,), x.dtype, row, mesh,
-                                  k=k)
 
     def kern(xs):
         me = jax.lax.axis_index(axis)
@@ -370,14 +328,7 @@ def distributed_topk(x: jax.Array, k: int, largest: bool = True,
         # >= k valid slots since k <= m <= n, so the k winners always
         # exist among valid candidates.) Tested with sentinel-extreme
         # data on a ragged last shard in tests/test_sort.py.
-        if topk_sel.pallas:
-            from ..kernels import topk as ktopk
-
-            lk, li = ktopk.shard_topk(key, k, _extreme(key.dtype,
-                                                       lo=True),
-                                      topk_sel)
-        else:
-            lk, li = jax.lax.top_k(key, k)
+        lk, li = jax.lax.top_k(key, k)
         lv = vv[li]
         gk = jax.lax.all_gather(lk, axis, tiled=True)       # (p*k,)
         gv = jax.lax.all_gather(lv, axis, tiled=True)
@@ -385,10 +336,9 @@ def distributed_topk(x: jax.Array, k: int, largest: bool = True,
         _, win = jax.lax.top_k(gk, k)
         return gv[win][None], gi[win][None].astype(jnp.int32)
 
-    kw = {"check_vma": False} if topk_sel.pallas else {}
     mapped = shard_map(
         kern, mesh=mesh, in_specs=(row.spec(),),
-        out_specs=(tiling_mod.Tiling((axis, None)).spec(),) * 2, **kw)
+        out_specs=(tiling_mod.Tiling((axis, None)).spec(),) * 2)
     vals, idx = mapped(xp)
     # every shard computed the same winners: shard 0's row is the answer
     return vals[0], idx[0]

@@ -3,13 +3,9 @@ SURVEY.md §2.3: convnet stencil/maxpool in some reference versions).
 
 TPU-native: the stencil is ``lax.conv_general_dilated`` (MXU) and pooling
 is ``lax.reduce_window`` (VPU), traced into the consuming jit like any
-map. :func:`stencil` now lowers through a dedicated :class:`StencilExpr`
-node: when the committed tiling shards the H axis, the kernel layer
-(``spartan_tpu/kernels/stencil.py``, docs/KERNELS.md) replaces GSPMD's
-generic halo collectives with an explicit ``ppermute`` halo exchange
-feeding a blocked Pallas conv kernel; every other case (stride > 1,
-non-SAME padding, unsharded spatial dims, non-f32) keeps the traced
-conv, where GSPMD partitions spatial dims with its own halo transfers.
+map. :func:`stencil` lowers through a dedicated :class:`StencilExpr`
+node; when the committed tiling shards the H axis, GSPMD partitions the
+conv with its own halo transfers.
 """
 
 from __future__ import annotations
@@ -31,12 +27,8 @@ def _pair(v: Stride) -> Tuple[int, int]:
 
 
 class StencilExpr(Expr):
-    """NHWC convolution with a kernel-layer lowering seam.
-
-    ``kernels.select('stencil', ...)`` decides per shape/tiling/
-    platform whether this node runs the manual-halo Pallas path or the
-    traced ``lax.conv`` (GSPMD halos); ``st.explain`` prints the
-    decision and the derived grid for the plan (docs/KERNELS.md)."""
+    """NHWC convolution: the traced ``lax.conv`` (GSPMD halos), with
+    batch/H shardings carried through to the output tiling."""
 
     def __init__(self, x: Expr, w: Expr, stride: Tuple[int, int],
                  padding: str):
@@ -62,24 +54,14 @@ class StencilExpr(Expr):
             dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
     def _lower(self, env: Dict[int, Any]) -> Any:
-        from ..kernels import registry as kernels_mod
-
-        xv = self.x.lower(env)
-        wv = self.w.lower(env)
-        sel = kernels_mod.node_selection(self)
-        if sel is not None and sel.pallas:
-            from ..kernels import stencil as kstencil
-
-            return kstencil.halo_stencil(xv, wv, self.x.out_tiling(),
-                                         sel)
-        return self._conv(xv, wv)
+        return self._conv(self.x.lower(env), self.w.lower(env))
 
     def _sig(self, ctx) -> Tuple:
         return ("stencil", self.stride, self.padding,
                 ctx.of(self.x), ctx.of(self.w))
 
     def _default_tiling(self) -> Tiling:
-        # batch/H shardings carry through (the halo path preserves
+        # batch/H shardings carry through (GSPMD's halo exchange keeps
         # them); the W window and output channels stay whole. The plan
         # sanitizes H away when the output height stops dividing.
         tx = self.x.out_tiling()

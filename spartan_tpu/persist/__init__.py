@@ -15,7 +15,7 @@ Addressing & safety (fingerprint.py / store.py):
 * entries are keyed by a process-stable digest of the SAME raw-DAG
   plan key ``evaluate()`` computes, extended with a full environment
   fingerprint (python/jax/jaxlib versions, platform, device count,
-  mesh shape + epoch, ``_opt_flags_key``, ``kernels.policy_key()``) —
+  mesh shape + epoch, ``_opt_flags_key``) —
   stale or foreign entries can never alias;
 * writes are atomic temp-dir + ``os.replace`` with per-file CRC32
   manifests (the PR-5 checkpoint discipline); concurrent replicas

@@ -22,8 +22,8 @@ reduced to its structural content:
 A digest alone must never authorize a load: :func:`env_fingerprint`
 captures everything OUTSIDE the plan key that changes what a compiled
 executable means — jax/jaxlib/python versions, platform, device
-count, mesh shape + epoch, the optimizer-flags key and the kernel
-policy — and the store validates the manifest's fingerprint verbatim
+count, mesh shape + epoch and the optimizer-flags key (the platform
+also decides the kernel backend) — and the store validates the manifest's fingerprint verbatim
 on every load, so a stale or foreign entry can never alias even under
 a digest collision.
 """
@@ -133,7 +133,6 @@ def env_fingerprint(mesh: Any) -> Dict[str, Any]:
     # lazy: expr.base imports this package at module init; by the time
     # a fingerprint is computed the expr layer is fully loaded
     from ..expr import base as expr_base
-    from ..kernels import registry as kernels_mod
 
     return {
         "format": FORMAT_VERSION,
@@ -146,7 +145,6 @@ def env_fingerprint(mesh: Any) -> Dict[str, Any]:
                        for k, v in sorted(mesh.shape.items())],
         "mesh_epoch": int(mesh_mod._EPOCH),
         "opt_flags": stable_digest(expr_base._opt_flags_key()),
-        "kernels_policy": stable_digest(kernels_mod.policy_key()),
     }
 
 

@@ -112,6 +112,36 @@ def test_stencil_top_level():
     np.testing.assert_allclose(out, expect, rtol=1e-6)
 
 
+def _np_conv_same(img, flt):
+    """NHWC x HWIO convolution, stride 1, SAME padding (the low side
+    takes the smaller half of an odd total, as XLA pads)."""
+    kh, kw = flt.shape[:2]
+    pads = [(0, 0)] + [((k - 1) // 2, k - 1 - (k - 1) // 2)
+                       for k in (kh, kw)] + [(0, 0)]
+    xp = np.pad(img.astype(np.float64), pads)
+    h, w = img.shape[1:3]
+    return sum(np.einsum("nhwc,co->nhwo", xp[:, i:i + h, j:j + w],
+                         flt[i, j].astype(np.float64))
+               for i in range(kh) for j in range(kw))
+
+
+@pytest.mark.parametrize("h,k", [(32, 3), (36, 2)],
+                         ids=["even_shard_odd_filter",
+                              "odd_shard_even_filter"])
+def test_stencil_h_sharded(h, k):
+    """A SAME stencil over images whose H axis is sharded four ways
+    (8- and 9-row shards) matches the NumPy convolution, and the output
+    keeps the H sharding."""
+    rng = np.random.RandomState(9)
+    img = rng.rand(2, h, 16, 8).astype(np.float32)
+    flt = rng.rand(k, k, 8, 4).astype(np.float32)
+    x = st.from_numpy(img, tiling=tiling.Tiling((None, "x", None, None)))
+    out = st.stencil(x, flt).evaluate()
+    assert out.tiling.axes[1] == "x"
+    np.testing.assert_allclose(out.glom(), _np_conv_same(img, flt),
+                               rtol=1e-4, atol=1e-5)
+
+
 def test_einsum_family(mesh2d):
     """einsum / tensordot / matmul / trace / inner vs NumPy oracles on
     sharded operands."""
@@ -208,6 +238,24 @@ def test_histogram_oracle(mesh1d):
     np.testing.assert_array_equal(
         np.asarray(c2d.glom()),
         np.histogram(m2, bins=8, range=(0.0, 1.0))[0])
+
+
+def test_histogram_data_range_exact(mesh1d):
+    """Data-dependent range over normal samples: the counts equal
+    np.histogram's over the returned edges' range, bin for bin."""
+    x = np.random.RandomState(1).randn(2000).astype(np.float32)
+    counts, edges = (a.glom() for a in st.histogram(x, bins=32))
+    want, _ = np.histogram(x, bins=32, range=(edges[0], edges[-1]))
+    np.testing.assert_array_equal(counts, want)
+
+
+def test_bincount_out_of_range_ids(mesh1d):
+    """Negative ids count in bucket 0 and ids >= length are dropped
+    (jnp.bincount's rule), over a ragged length."""
+    ids = np.random.RandomState(0).randint(-3, 14, 1003).astype(np.int32)
+    keep = ids < 10
+    want = np.bincount(np.maximum(ids[keep], 0), minlength=10)
+    np.testing.assert_array_equal(st.bincount(ids, length=10).glom(), want)
 
 
 def test_histogram_edge_cases(mesh1d):

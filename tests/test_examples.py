@@ -210,7 +210,7 @@ def test_kmeans_fused_kernel_oracle():
     import jax
     import jax.numpy as jnp
 
-    from spartan_tpu.ops import kmeans as kk
+    from spartan_tpu.kernels import kmeans as kk
 
     rng = np.random.RandomState(5)
     n, d, k = 3000, 128, 7          # pads to 3072
@@ -232,7 +232,7 @@ def test_kmeans_fused_run_matches_step():
     import jax
     import jax.numpy as jnp
 
-    from spartan_tpu.ops import kmeans as kk
+    from spartan_tpu.kernels import kmeans as kk
 
     rng = np.random.RandomState(6)
     pts = jnp.asarray(rng.rand(2048, 128).astype(np.float32))

@@ -1,32 +1,28 @@
-"""Partitionable Pallas kernel layer (ISSUE 12): tiling->grid
-derivation property tests over the tiling vocabulary, CPU
-interpret-mode parity for every kernel (bit-compare where the op is
-deterministic), plan/compile-key separation between the pallas and
-gspmd backends, selection-fallback reasons, and the st.explain
-surface. docs/KERNELS.md documents the contracts asserted here."""
+"""Pallas kernel layer: tiling->grid derivation property tests over
+the tiling vocabulary, the k-means kernel's selection fallback reasons,
+and its CPU interpret-mode parity. docs/KERNELS.md documents the
+contracts asserted here."""
 
 import numpy as np
 import pytest
 
-import spartan_tpu as st
 from spartan_tpu.array import tiling
-from spartan_tpu.expr import base
 from spartan_tpu.kernels import registry as kreg
 from spartan_tpu.parallel import mesh as mesh_mod
-from spartan_tpu.utils.config import FLAGS
 
 jax = mesh_mod.jax
 jnp = jax.numpy
 
 
-@pytest.fixture(autouse=True)
-def _flags():
-    yield
-    FLAGS.reset_all()
-
-
 VOCAB = [tiling.replicated, tiling.row, tiling.col, tiling.block,
          tiling.row_t, tiling.col_t, tiling.block_t, tiling.flat_row]
+
+
+@pytest.fixture()
+def pallas(monkeypatch):
+    """Kernel selection as on the chip; the kernels run in interpret
+    mode here."""
+    monkeypatch.setattr(kreg, "mode", lambda: "pallas")
 
 
 # -- tiling -> grid derivation ---------------------------------------
@@ -80,210 +76,27 @@ def test_derive_indivisible_falls_back_with_reason(mesh1d):
     mesh = mesh_mod.get_mesh()
     sched, why = kreg.derive((10,), tiling.row(1), np.float32, mesh)
     assert sched is None and "divide" in why
-    # and the selection layer surfaces the same reason
-    FLAGS.native_kernels = "on"
-    sel = kreg.select("kmeans", (1025, 128), np.float32,
-                      tiling.row(2), k=4, block=1024)
-    assert not sel.pallas and "divisible" in sel.reason
 
 
-def test_select_gating_and_fallback_reasons(mesh1d):
-    FLAGS.native_kernels = "off"
-    sel = kreg.select("topk", (128,), np.float32, tiling.row(1), k=4)
-    assert sel.backend == "gspmd" and "off" in sel.reason
-    FLAGS.native_kernels = "auto"  # CPU: portable lowering unchanged
-    sel = kreg.select("topk", (128,), np.float32, tiling.row(1), k=4)
-    assert sel.backend == "gspmd" and "platform" in sel.reason
-    FLAGS.native_kernels = "on"
-    assert kreg.select("topk", (128,), np.float32, tiling.row(1),
-                       k=4).pallas
-    # per-op constraints fall back with the reason recorded
-    sel = kreg.select("topk", (512,), np.float32, tiling.row(1), k=200)
-    assert not sel.pallas and "128" in sel.reason
-    sel = kreg.select("topk", (512,), np.float16, tiling.row(1), k=4)
-    assert not sel.pallas and "4-byte" in sel.reason
-    sel = kreg.select("bincount", (64, 4), np.int32,
-                      tiling.replicated(2), length=8)
-    assert not sel.pallas and "1-D" in sel.reason
-    sel = kreg.select("bincount", (512,), np.int32, tiling.row(1),
-                      length=65536)
-    assert not sel.pallas and "4096" in sel.reason
-    # the measured-off table keeps segment_sum portable ONLY in auto;
-    # the explicit parity mode still selects it
-    assert kreg.select("segment_sum", (512, 8), np.float32,
-                       tiling.row(2), num_segments=16).pallas
+@pytest.mark.parametrize("n,d,k,dtype,why", [
+    (8 * 1024, 128, 8, np.float16, "dtype"),
+    (8 * 1024, 100, 8, np.float32, "multiple of 128"),
+    (8 * 1024, 128, 200, np.float32, "> 128"),
+    (1025 * 8 + 1, 128, 8, np.float32, "divisible"),
+    (8 * 1000, 128, 8, np.float32, "point block"),
+], ids=["dtype", "d", "k", "shards", "block"])
+def test_select_gating_and_fallback_reasons(mesh1d, pallas, n, d, k,
+                                            dtype, why):
+    """Each constraint of the k-means kernel falls back to the XLA
+    path with its reason recorded."""
+    sel = kreg.select("kmeans", (n, d), dtype, tiling.row(2), k=k,
+                      block=1024)
+    assert sel.backend == "gspmd" and why in sel.reason, sel.reason
 
 
-def test_policy_key_tracks_flag(mesh1d):
-    FLAGS.native_kernels = "off"
-    off = kreg.policy_key()
-    FLAGS.native_kernels = "on"
-    on = kreg.policy_key()
-    FLAGS.native_kernels = "auto"
-    auto = kreg.policy_key()
-    assert on != off
-    # CPU auto IS the portable path: it aliases `off` on purpose (the
-    # lowering is provably unchanged), and never aliases `on`
-    assert auto == off
-    assert auto != on
+def test_kmeans_sharded_kernel_parity(mesh1d, pallas):
+    from spartan_tpu.kernels import kmeans as kk
 
-
-# -- interpret-mode parity (CPU CI exercises every kernel) -----------
-
-
-def test_bincount_parity_bit_equal(mesh1d):
-    rng = np.random.RandomState(0)
-    ids = rng.randint(-3, 14, 1003).astype(np.int32)  # oob both ends
-    FLAGS.native_kernels = "off"
-    ref = st.bincount(ids, length=10).glom()
-    FLAGS.native_kernels = "on"
-    out = st.bincount(ids, length=10).glom()
-    np.testing.assert_array_equal(ref, out)
-    exp = np.bincount(np.clip(ids, 0, None)[ids < 10].clip(0, 9),
-                      minlength=10)
-    np.testing.assert_array_equal(out, exp)
-
-
-def test_histogram_parity(mesh1d):
-    rng = np.random.RandomState(1)
-    x = rng.randn(2000).astype(np.float32)
-    FLAGS.native_kernels = "off"
-    c0, e0 = (a.glom() for a in st.histogram(x, bins=32))
-    FLAGS.native_kernels = "on"
-    c1, e1 = (a.glom() for a in st.histogram(x, bins=32))
-    np.testing.assert_array_equal(c0, c1)
-    np.testing.assert_array_equal(e0, e1)
-    cn, _ = np.histogram(x, bins=32, range=(e0[0], e0[-1]))
-    np.testing.assert_array_equal(c1, cn)
-
-
-def test_topk_parity_ties_and_ragged(mesh1d):
-    rng = np.random.RandomState(2)
-    # ragged last shard + duplicated values exercise the tie-break
-    v = np.repeat(rng.rand(173).astype(np.float32), 3)[:515]
-    for largest in (True, False):
-        FLAGS.native_kernels = "off"
-        v0, i0 = (a.glom() for a in st.topk(v, 9, largest=largest))
-        FLAGS.native_kernels = "on"
-        v1, i1 = (a.glom() for a in st.topk(v, 9, largest=largest))
-        np.testing.assert_array_equal(v0, v1)
-        np.testing.assert_array_equal(i0, i1)
-
-
-def test_topk_parity_ints_smallest(mesh1d):
-    rng = np.random.RandomState(3)
-    vi = rng.randint(-2 ** 31 + 1, 2 ** 31 - 1, 512).astype(np.int32)
-    vi[7] = np.iinfo(np.int32).min  # the sentinel-extreme edge
-    FLAGS.native_kernels = "off"
-    v0, i0 = (a.glom() for a in st.topk(vi, 5, largest=False))
-    FLAGS.native_kernels = "on"
-    v1, i1 = (a.glom() for a in st.topk(vi, 5, largest=False))
-    np.testing.assert_array_equal(v0, v1)
-    np.testing.assert_array_equal(i0, i1)
-    assert v1[0] == np.iinfo(np.int32).min
-
-
-def test_sample_sort_pack_bit_equal_with_nan(mesh1d):
-    rng = np.random.RandomState(4)
-    v = rng.randn(1013).astype(np.float32)
-    v[[3, 500, 1012]] = np.nan  # NaN payloads must survive the pack
-    FLAGS.native_kernels = "off"
-    s0 = st.sort(v).glom()
-    FLAGS.native_kernels = "on"
-    s1 = st.sort(v).glom()
-    np.testing.assert_array_equal(s0.view(np.uint32),
-                                  s1.view(np.uint32))
-    FLAGS.native_kernels = "off"
-    a0 = st.argsort(v).glom()
-    FLAGS.native_kernels = "on"
-    a1 = st.argsort(v).glom()
-    np.testing.assert_array_equal(a0, a1)
-
-
-def test_batched_sort_pack_parity(mesh1d):
-    rng = np.random.RandomState(5)
-    b = rng.rand(4, 513).astype(np.float32)
-    FLAGS.native_kernels = "off"
-    s0 = st.sort(st.as_expr(b), axis=1).glom()
-    FLAGS.native_kernels = "on"
-    s1 = st.sort(st.as_expr(b), axis=1).glom()
-    np.testing.assert_array_equal(s0, s1)
-    np.testing.assert_array_equal(s1, np.sort(b, axis=1))
-
-
-def test_partition_pack_unit_bit_exact(mesh1d):
-    """The pack kernel against the scatter formulation it replaces,
-    over every 4-byte dtype and hostile bit patterns."""
-    from spartan_tpu.kernels import exchange as kex
-
-    rng = np.random.RandomState(6)
-    p, m = 8, 37
-    for dt in (np.float32, np.int32, np.uint32):
-        xs = rng.randint(0, 2 ** 32, m, np.uint64).astype(np.uint32)
-        if dt == np.float32:
-            xs = xs.view(np.float32)  # includes NaN/denormal patterns
-        else:
-            xs = xs.astype(dt)
-        counts = np.array([10, 0, 20, 2, 0, 1, 3, 1], np.int32)
-        starts = np.concatenate([[0], np.cumsum(counts)[:-1]]).astype(
-            np.int32)
-        FLAGS.native_kernels = "on"
-        sel = kreg.select("sort_exchange", (p * m,), dt,
-                          tiling.row(1), p=p, m=m)
-        assert sel.pallas
-        out = np.asarray(kex.partition_pack(
-            jnp.asarray(xs), jnp.asarray(starts), jnp.asarray(counts),
-            p, sel))
-        ref = np.zeros((p, m), dt)
-        for j in range(p):
-            ref[j, :counts[j]] = xs[starts[j]:starts[j] + counts[j]]
-        np.testing.assert_array_equal(
-            out.view(np.uint32), ref.view(np.uint32))
-
-
-def test_segment_sum_pallas_parity_bit_equal(mesh1d):
-    """Integer-valued f32 streams: the one-hot MXU merge must agree
-    with XLA's scatter bit for bit (both are exact there)."""
-    from spartan_tpu.ops.segment import segment_count, segment_sum
-
-    rng = np.random.RandomState(7)
-    vals = rng.randint(-8, 9, (1000, 16)).astype(np.float32)
-    ids = rng.randint(-2, 20, 1000)  # oob dropped on both ends
-    ref = np.asarray(segment_sum(jnp.asarray(vals), jnp.asarray(ids),
-                                 12, impl="xla"))
-    out = np.asarray(segment_sum(jnp.asarray(vals), jnp.asarray(ids),
-                                 12, impl="pallas"))
-    np.testing.assert_array_equal(ref.view(np.uint32),
-                                  out.view(np.uint32))
-    # the psum_scatter merge leg: k divisible by the shard count
-    out16 = np.asarray(segment_sum(jnp.asarray(vals),
-                                   jnp.asarray(ids), 16,
-                                   impl="pallas"))
-    ref16 = np.asarray(segment_sum(jnp.asarray(vals),
-                                   jnp.asarray(ids), 16, impl="xla"))
-    np.testing.assert_array_equal(ref16, out16)
-    # 1-D stream + counts
-    cnt = np.asarray(segment_count(jnp.asarray(ids.clip(0, 11)), 12,
-                                   impl="pallas"))
-    np.testing.assert_array_equal(
-        cnt, np.bincount(ids.clip(0, 11), minlength=12))
-
-
-def test_segment_auto_policy_unchanged_on_cpu(mesh1d):
-    """auto keeps XLA's scatter (the measured-win contract): the
-    selection reason names the measurement."""
-    sel = kreg.select("segment_sum", (512, 8), np.float32,
-                      tiling.row(2), num_segments=16)
-    assert not sel.pallas
-    FLAGS.native_kernels = "on"
-    assert kreg.select("segment_sum", (512, 8), np.float32,
-                       tiling.row(2), num_segments=16).pallas
-
-
-def test_kmeans_sharded_kernel_parity(mesh1d):
-    from spartan_tpu.ops import kmeans as kk
-
-    FLAGS.native_kernels = "on"
     n, d, k = 8 * 1024, 128, 8
     assert kk.supports(n, d, k)
     rng = np.random.RandomState(8)
@@ -310,130 +123,11 @@ def test_kmeans_sharded_kernel_parity(mesh1d):
         np.asarray(c2), np.bincount(a[:nv], minlength=k))
 
 
-def test_kmeans_supports_respects_policy(mesh1d):
-    from spartan_tpu.ops import kmeans as kk
+def test_kmeans_supports_respects_policy(mesh1d, monkeypatch):
+    """Pallas on TPU only; the constraints are the parametrised cases
+    of test_select_gating_and_fallback_reasons."""
+    from spartan_tpu.kernels import kmeans as kk
 
-    assert not kk.supports(8 * 1024, 128, 8)  # auto on CPU: portable
-    FLAGS.native_kernels = "on"
+    assert not kk.supports(8 * 1024, 128, 8)  # CPU: the XLA path
+    monkeypatch.setattr(kreg, "mode", lambda: "pallas")
     assert kk.supports(8 * 1024, 128, 8)      # multi-shard, parity
-    assert not kk.supports(8 * 1024, 100, 8)  # d % 128
-    assert not kk.supports(8 * 1024, 128, 200)  # k > 128
-
-
-def test_stencil_halo_parity(mesh1d):
-    rng = np.random.RandomState(9)
-    img = rng.rand(2, 64, 16, 8).astype(np.float32)
-    flt = rng.rand(3, 3, 8, 4).astype(np.float32)
-
-    def build():
-        xe = st.as_expr(img)
-        xe._forced_tiling = tiling.Tiling((None, "x", None, None))
-        return st.stencil(xe, flt)
-
-    FLAGS.native_kernels = "off"
-    ref = build().glom()
-    FLAGS.native_kernels = "on"
-    sel = kreg.node_selection(build())
-    assert sel is not None and sel.pallas
-    out = build().glom()
-    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-5)
-    # even filter: asymmetric SAME pad split must match XLA's
-    flt2 = rng.rand(2, 2, 8, 4).astype(np.float32)
-    FLAGS.native_kernels = "off"
-    xe = st.as_expr(img)
-    xe._forced_tiling = tiling.Tiling((None, "x", None, None))
-    ref2 = st.stencil(xe, flt2).glom()
-    FLAGS.native_kernels = "on"
-    xe = st.as_expr(img)
-    xe._forced_tiling = tiling.Tiling((None, "x", None, None))
-    out2 = st.stencil(xe, flt2).glom()
-    np.testing.assert_allclose(out2, ref2, rtol=1e-4, atol=1e-5)
-
-
-def test_stencil_fallbacks(mesh1d):
-    FLAGS.native_kernels = "on"
-    rng = np.random.RandomState(10)
-    img = rng.rand(2, 64, 16, 8).astype(np.float32)
-    flt = rng.rand(3, 3, 8, 4).astype(np.float32)
-    # H unsharded -> GSPMD needs no halo exchange
-    xe = st.as_expr(img)
-    xe._forced_tiling = tiling.Tiling((None, None, None, None))
-    sel = kreg.node_selection(st.stencil(xe, flt))
-    assert not sel.pallas and "halo" in sel.reason
-    # stride 2 keeps the traced conv
-    xe = st.as_expr(img)
-    xe._forced_tiling = tiling.Tiling((None, "x", None, None))
-    e = st.stencil(xe, flt, stride=2)
-    sel = kreg.node_selection(e)
-    assert not sel.pallas and "stride" in sel.reason
-    out = e.glom()  # and the fallback actually evaluates
-    assert out.shape == (2, 32, 8, 4)
-
-
-# -- cache-key separation (acceptance) --------------------------------
-
-
-def test_plan_and_compile_keys_never_alias(mesh1d):
-    """pallas/gspmd variants of the same expr: distinct plan keys,
-    distinct compiled executables, identical (bit-equal) results."""
-    rng = np.random.RandomState(11)
-    ids = rng.randint(0, 10, 1000).astype(np.int32)
-
-    def build():
-        return st.bincount(ids, length=10)
-
-    FLAGS.native_kernels = "off"
-    e_off = build()
-    key_off = base.plan_signature(e_off)[0]
-    r_off = e_off.glom()
-    FLAGS.native_kernels = "on"
-    e_on = build()
-    key_on = base.plan_signature(e_on)[0]
-    r_on = e_on.glom()
-    assert key_off != key_on
-    # both plans live in the cache side by side (no alias, no evict)
-    assert base.lookup_plan(key_off) is not None
-    assert base.lookup_plan(key_on) is not None
-    # and their compiled executables are keyed apart too
-    assert base.lookup_plan(key_off).key != base.lookup_plan(key_on).key
-    np.testing.assert_array_equal(r_off, r_on)
-
-
-def test_auto_on_cpu_is_the_off_plan(mesh1d):
-    """With native_kernels=auto on CPU the lowering is PROVABLY
-    unchanged: the plan key equals the off key, so the same compiled
-    executable serves both (the kernels_off_overhead contract)."""
-    rng = np.random.RandomState(12)
-    v = rng.rand(512).astype(np.float32)
-
-    def build():
-        return st.topk(v, 4)[1]
-
-    FLAGS.native_kernels = "off"
-    key_off = base.plan_signature(build())[0]
-    FLAGS.native_kernels = "auto"
-    key_auto = base.plan_signature(build())[0]
-    assert key_off == key_auto
-
-
-# -- explain surface --------------------------------------------------
-
-
-def test_explain_names_backend_and_grid(mesh1d):
-    rng = np.random.RandomState(13)
-    v = rng.rand(512).astype(np.float32)
-    FLAGS.native_kernels = "on"
-    rep = st.explain(st.topk(v, 4)[1], cost=False)
-    entries = rep.data.get("kernels") or []
-    topk_entries = [e for e in entries if e["op"] == "topk"]
-    assert topk_entries and topk_entries[0]["backend"] == "pallas"
-    assert tuple(topk_entries[0]["grid"]) and topk_entries[0]["block"]
-    text = str(rep)
-    assert "backend=pallas" in text and "grid=" in text
-    # fallback nodes carry their reason in the same section
-    FLAGS.native_kernels = "off"
-    rep2 = st.explain(st.topk(v, 5)[1], cost=False)
-    entries2 = rep2.data.get("kernels") or []
-    assert entries2 and all(e["backend"] == "gspmd" for e in entries2)
-    assert any("off" in (e.get("reason") or "") for e in entries2)
-    assert "backend=gspmd" in str(rep2)

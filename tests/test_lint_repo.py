@@ -438,7 +438,7 @@ def test_pallas_allowed_in_kernel_layer():
         "from jax.experimental.pallas import tpu as pltpu\n"
         "out = pl.pallas_call(kern, out_shape=shape)(x)\n")
     for rel in (os.path.join("spartan_tpu", "kernels", "segment.py"),
-                os.path.join("spartan_tpu", "kernels", "topk.py")):
+                os.path.join("spartan_tpu", "kernels", "kmeans.py")):
         path = os.path.join(lint_repo.REPO, rel)
         assert lint_repo.lint_pallas_imports(path, tree) == []
     # a Selection.pallas property read is NOT the pallas module

@@ -186,27 +186,50 @@ def test_argsort_axis_sharded(mesh1d):
         np.testing.assert_array_equal(a[r][perm[r]], np.sort(a[r]))
 
 
-def test_sample_sort_inf_values(mesh1d):
-    """Data containing +/-inf must not collide with exchange padding."""
-    rng = np.random.RandomState(7)
-    a = rng.rand(4096).astype(np.float32)
+def _inf_values():
+    a = np.random.RandomState(7).rand(4096).astype(np.float32)
     a[::100] = np.inf
     a[::173] = -np.inf
-    e = st.sort(st.from_numpy(a, tiling=tiling.row(1)))
-    np.testing.assert_array_equal(np.asarray(e.glom()), np.sort(a))
+    return a, tiling.row(1)
 
 
-def test_sample_argsort_oracle(mesh1d):
+def _nan_values():
+    """A ragged length with NaNs, which sort after +inf."""
+    a = np.random.RandomState(4).randn(1013).astype(np.float32)
+    a[[3, 500, 1012]] = np.nan
+    return a, None
+
+
+def _uniform_values():
+    return (np.random.RandomState(8).rand(65_536).astype(np.float32),
+            tiling.row(1))
+
+
+@pytest.mark.parametrize("make", [_inf_values, _nan_values],
+                         ids=["inf", "nan"])
+def test_sample_sort_inf_values(mesh1d, make):
+    """Data containing +/-inf or NaN must not collide with exchange
+    padding; NaN payloads survive the exchange bit for bit."""
+    a, t = make()
+    e = st.sort(st.from_numpy(a, tiling=t))
+    assert isinstance(e, SampleSortExpr)
+    np.testing.assert_array_equal(np.asarray(e.glom()).view(np.uint32),
+                                  np.sort(a).view(np.uint32))
+
+
+@pytest.mark.parametrize("make", [_uniform_values, _nan_values],
+                         ids=["uniform", "nan"])
+def test_sample_argsort_oracle(mesh1d, make):
     """Distributed argsort: x[perm] is sorted and perm is a true
     permutation (np.argsort's exact tie order is not guaranteed)."""
-    rng = np.random.RandomState(8)
-    a = rng.rand(65_536).astype(np.float32)
-    e = st.argsort(st.from_numpy(a, tiling=tiling.row(1)))
+    a, t = make()
+    e = st.argsort(st.from_numpy(a, tiling=t))
     assert isinstance(e, SampleSortExpr) and e.indices
     perm = np.asarray(e.glom())
     assert perm.dtype == np.int32
     assert np.array_equal(np.sort(perm), np.arange(a.size))
-    np.testing.assert_array_equal(a[perm], np.sort(a))
+    np.testing.assert_array_equal(a[perm].view(np.uint32),
+                                  np.sort(a).view(np.uint32))
 
 
 def test_sample_argsort_duplicates(mesh2d):
@@ -441,6 +464,22 @@ def test_topk_distributed(mesh1d):
         np.testing.assert_array_equal(gv, ref)
     with pytest.raises(ValueError, match="1 <= k"):
         st.topk(fc, 0)
+
+
+@pytest.mark.parametrize("largest", [True, False],
+                         ids=["largest", "smallest"])
+def test_topk_ties_ragged(mesh1d, largest):
+    """Every value thrice, on a ragged last shard: the winners are the
+    k best values, each at a distinct real index holding that value."""
+    rng = np.random.RandomState(2)
+    a = np.repeat(rng.rand(173).astype(np.float32), 3)[:515]
+    vals, idx = st.topk(st.from_numpy(a), 9, largest=largest)
+    gv, gi = np.asarray(vals.glom()), np.asarray(idx.glom())
+    ref = np.sort(a)[::-1][:9] if largest else np.sort(a)[:9]
+    np.testing.assert_array_equal(gv, ref)
+    assert gi.min() >= 0 and gi.max() < a.size
+    assert len(set(gi.tolist())) == 9
+    np.testing.assert_array_equal(a[gi], gv)
 
 
 def test_topk_sentinel_extreme_ragged(mesh1d):

@@ -1,4 +1,5 @@
-"""Compile the chip's kernels at real widths for a described v5e:2x2.
+"""Compile the chip's kernels, and the XLA lowerings of the irregular
+ops, at real widths for a described v5e:2x2.
 
 Nothing runs: the TPU compiler, installed here, compiles for a chip
 that is described and not attached, and refuses what Mosaic or XLA on
@@ -191,60 +192,97 @@ def test_pagerank_loop(topo, mosaic):
     assert " gather(" not in txt
 
 
+def _no_kernel(txt: str) -> None:
+    assert "tpu_custom_call" not in txt
+
+
 @pytest.mark.parametrize("shape", [(1 << 20, 64), (1 << 20,)])
-def test_segment_sum_block(topo, shape):
-    from spartan_tpu.kernels.segment import segment_sum_block
-
-    mesh = _mesh(topo, (1, 1))
-    txt = _compiled_text(
-        segment_sum_block, _sds(shape, F32, mesh),
-        _sds((shape[0],), I32, mesh), num_segments=64, interpret=False)
-    assert "tpu_custom_call" in txt
-
-
-def test_shard_topk(topo, mosaic):
-    """distributed top-k's local stage: k=64 of a 4M-element shard
-    (16M over the four chips)."""
-    from spartan_tpu.kernels.topk import shard_topk
+def test_segment_sum(topo, mosaic, shape):
+    """The segment-sum merge (naive Bayes' class sums, the sparse
+    paths' todense and row sums): XLA's scatter over 1M entries sharded
+    across four chips into 64 segments."""
+    from spartan_tpu.ops.segment import segment_sum
 
     mesh = _mesh(topo, (4, 1))
-    sel = registry.select("topk", (16 << 20,), np.float32,
-                          tiling_mod.row(1), mesh, k=64)
-    assert sel.pallas and not sel.interpret, sel.reason
-    txt = jax.jit(lambda key: shard_topk(key, 64, -np.inf, sel)).lower(
-        _sds((4 << 20,), F32, _mesh(topo, (1, 1)))).compile().as_text()
-    assert "tpu_custom_call" in txt
+    rows = P(tiling_mod.AXIS_ROW, *([None] * (len(shape) - 1)))
+    _no_kernel(_compiled_text(
+        segment_sum, _sds(shape, F32, mesh, rows),
+        _sds((shape[0],), I32, mesh, P(tiling_mod.AXIS_ROW)),
+        num_segments=64))
 
 
-def test_partition_pack(topo, mosaic):
-    """The padded sample-sort exchange's send-buffer pack at the widest
-    shard the selection admits (512K elements, 2M over 4 chips)."""
-    from spartan_tpu.kernels.exchange import partition_pack
-
-    mesh = _mesh(topo, (4, 1))
-    m = 1 << 19
-    sel = registry.select("sort_exchange", (4 * m,), np.float32,
-                          tiling_mod.row(1), mesh, p=4, m=m)
-    assert sel.pallas and not sel.interpret, sel.reason
-    one = _mesh(topo, (1, 1))
-    txt = jax.jit(lambda xs, s, c: partition_pack(xs, s, c, 4, sel)).lower(
-        _sds((m,), F32, one), _sds((4,), I32, one),
-        _sds((4,), I32, one)).compile().as_text()
-    assert "tpu_custom_call" in txt
-
-
-def test_sort_exchange_four_chips(topo, mosaic):
-    """A 1-D 16M-element sample sort over four chips: both exchanges
-    are fixed-size all-to-alls, and the program fits the chip (the
-    ragged_all_to_all transport it replaced needed 16 GB here)."""
+@pytest.mark.parametrize("shape", [(2, 2), (4, 1)], ids=["2x2", "4x1"])
+def test_sort_exchange_four_chips(topo, mosaic, shape):
+    """A 1-D 16M-element sample sort on the two meshes the four-chip
+    smoke run sorts on: the send buffers are packed by XLA's scatter,
+    both exchanges are fixed-size all-to-alls, and the program fits the
+    chip (the ragged_all_to_all transport it replaced needed 16 GB)."""
     from spartan_tpu.ops import sort as sort_ops
 
-    mesh = _mesh(topo, (4, 1))
+    mesh = _mesh(topo, shape)
     compiled = jax.jit(lambda v: sort_ops.sample_sort(v, mesh)).lower(
         _sds((16 << 20,), F32, mesh, P(tiling_mod.AXIS_ROW))).compile()
     txt = compiled.as_text()
+    _no_kernel(txt)
     assert "all-to-all" in txt and "ragged-all-to-all" not in txt
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_distributed_topk_four_chips(topo, mosaic):
+    """k=64 of 16M elements over four chips: a per-shard lax.top_k,
+    then a gather of the 4 x 64 candidates."""
+    from spartan_tpu.ops import sort as sort_ops
+
+    mesh = _mesh(topo, (4, 1))
+    txt = _compiled_text(
+        lambda v: sort_ops.distributed_topk(v, 64, mesh=mesh),
+        _sds((16 << 20,), F32, mesh, P(tiling_mod.AXIS_ROW)))
+    _no_kernel(txt)
+
+
+def _val(shape, dtype, spec):
+    """A leaf standing for an operand of this shape and placement."""
+    from types import SimpleNamespace
+
+    from spartan_tpu.expr.base import ValExpr
+
+    return ValExpr(SimpleNamespace(shape=shape, dtype=np.dtype(dtype),
+                                   tiling=tiling_mod.Tiling(tuple(spec))))
+
+
+def test_bincount_four_chips(topo, mosaic):
+    """``st.bincount`` over 1M ids sharded across four chips, lowered
+    as BincountExpr lowers it."""
+    from spartan_tpu.expr.builtins import BincountExpr
+    from spartan_tpu.parallel import mesh as mesh_mod
+
+    mesh = _mesh(topo, (4, 1))
+    rows = P(tiling_mod.AXIS_ROW)
+    ids = _val((1 << 20,), I32, rows)
+    with mesh_mod.use_mesh(mesh):
+        e = BincountExpr(ids, 4096)
+        txt = _compiled_text(lambda v: e.lower({ids._id: v}),
+                             _sds((1 << 20,), I32, mesh, rows))
+    _no_kernel(txt)
+
+
+def test_stencil_h_sharded(topo, mosaic):
+    """A 3x3 SAME stencil over 8 x 224 x 224 x 64 images whose H axis
+    is sharded across four chips: GSPMD's conv with its own halos."""
+    from spartan_tpu.ops.stencil import StencilExpr
+    from spartan_tpu.parallel import mesh as mesh_mod
+
+    mesh = _mesh(topo, (4, 1))
+    h = P(None, tiling_mod.AXIS_ROW, None, None)
+    x = _val((8, 224, 224, 64), F32, h)
+    w = _val((3, 3, 64, 64), F32, P(None, None, None, None))
+    with mesh_mod.use_mesh(mesh):
+        e = StencilExpr(x, w, (1, 1), "SAME")
+        txt = _compiled_text(
+            lambda xv, wv: e.lower({x._id: xv, w._id: wv}),
+            _sds((8, 224, 224, 64), F32, mesh, h),
+            _sds((3, 3, 64, 64), F32, mesh))
+    _no_kernel(txt)
 
 
 def _named_kernel_text(topo, kernel: str) -> str:
@@ -268,32 +306,14 @@ def _named_kernel_text(topo, kernel: str) -> str:
             windowed_segsum, _sds((grand,), F32, mesh),
             _sds((grand // 128, 128), I32, mesh),
             _sds((grand // 1024,), I32, mesh), **static)
-    if kernel == "windowed_gather":
-        from spartan_tpu.kernels.segment import windowed_gather
+    from spartan_tpu.kernels.segment import windowed_gather
 
-        return _compiled_text(windowed_gather,
-                              *_gather_args(mesh, 1 << 14, 256))
-    if kernel == "segment_sum_block":
-        from spartan_tpu.kernels.segment import segment_sum_block
-
-        return _compiled_text(segment_sum_block, _sds((8192,), F32, mesh),
-                              _sds((8192,), I32, mesh), num_segments=64,
-                              interpret=False)
-    if kernel == "bincount_block":
-        from spartan_tpu.kernels.histogram import bincount_block
-
-        return _compiled_text(bincount_block, _sds((8192,), I32, mesh),
-                              length=64, interpret=False)
-    from spartan_tpu.kernels.stencil import conv_block
-
-    return _compiled_text(conv_block, _sds((1, 18, 18, 128), F32, mesh),
-                          _sds((3, 3, 128, 128), F32, mesh), hb=8,
-                          interpret=False)
+    return _compiled_text(windowed_gather,
+                          *_gather_args(mesh, 1 << 14, 256))
 
 
 @pytest.mark.parametrize("kernel", ["kmeans_lloyd", "windowed_segsum",
-                                    "windowed_gather", "segment_sum_block",
-                                    "bincount_block", "conv_block"])
+                                    "windowed_gather"])
 def test_kernel_carries_its_name(topo, mosaic, kernel):
     """The compiled custom call is named by its ``pallas_call``'s
     ``name``, which the device trace's op events carry."""
@@ -307,9 +327,6 @@ def test_dot_8192_committed_plan(topo):
     2x2 mesh, default precision — lowered as the smart-tiling pass
     commits it for the described chip: the operands' panels gathered
     in bf16, no all-reduce of a partial product, the output (x, y)."""
-    from types import SimpleNamespace
-
-    from spartan_tpu.expr.base import ValExpr
     from spartan_tpu.expr.dot import DotExpr
     from spartan_tpu.expr.tiling_cost import assign_tilings
     from spartan_tpu.parallel import mesh as mesh_mod
@@ -317,9 +334,7 @@ def test_dot_8192_committed_plan(topo):
     n = 8192
     mesh = _mesh(topo, (2, 2))
     xy = P(tiling_mod.AXIS_ROW, tiling_mod.AXIS_COL)
-    a, b = (ValExpr(SimpleNamespace(shape=(n, n), dtype=np.dtype(F32),
-                                    tiling=tiling_mod.Tiling(tuple(xy))))
-            for _ in range(2))
+    a, b = (_val((n, n), F32, xy) for _ in range(2))
     with mesh_mod.use_mesh(mesh):
         d = assign_tilings(DotExpr(a, b))
         compiled = jax.jit(lambda x, y: d.lower({a._id: x, b._id: y})

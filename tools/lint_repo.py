@@ -117,10 +117,9 @@ Eighteen repo-specific rules that generic linters cannot know:
     report can never map back to an expr node.
 
 12. No ``jax.experimental.pallas`` import (or ``pallas_call`` use)
-    outside ``spartan_tpu/kernels/`` (the partitionable-kernel PR):
-    every Pallas kernel goes through the kernel layer so its grid
-    derives from the committed tiling and its backend choice is
-    keyed, selectable and explainable (docs/KERNELS.md).
+    outside ``spartan_tpu/kernels/``: every Pallas kernel goes
+    through the kernel layer so its grid derives from the committed
+    tiling and its backend follows the platform (docs/KERNELS.md).
 
 13. No JAX AOT executable-serialization use
     (``jax.experimental.serialize_executable`` — ``serialize`` /
@@ -312,10 +311,10 @@ _PERSIST_SERIALIZE_NAMES = {"serialize_executable",
 
 # rule 12: Pallas is the kernel layer's private dependency. A raw
 # pallas_call outside spartan_tpu/kernels/ bypasses the selection
-# policy (kernels.select), the tiling->grid derivation, the
-# plan/compile-key separation and the interpret-mode parity contract
-# (docs/KERNELS.md) — exactly the single-device dead ends the seed's
-# ops/kmeans.py and ops/segment.py kernels were.
+# policy (kernels.registry.select), the tiling->grid derivation and
+# interpret mode off the chip (docs/KERNELS.md) — exactly the
+# single-device dead ends the seed's ops/kmeans.py and ops/segment.py
+# kernels were.
 _PALLAS_ALLOWED_DIRS = (os.path.join("spartan_tpu", "kernels")
                         + os.sep,)
 
@@ -895,8 +894,7 @@ def lint_pallas_imports(path: str, tree: ast.AST) -> List[Finding]:
     """Rule 12: no ``jax.experimental.pallas`` import (or
     ``pallas_call`` use) outside ``spartan_tpu/kernels/`` — every
     Pallas kernel goes through the kernel layer so its grid derives
-    from the committed tiling and its backend choice is keyed,
-    selectable and explainable."""
+    from the committed tiling and its backend follows the platform."""
     rel = os.path.relpath(path, REPO)
     if any(rel.startswith(d) for d in _PALLAS_ALLOWED_DIRS):
         return []
@@ -908,7 +906,7 @@ def lint_pallas_imports(path: str, tree: ast.AST) -> List[Finding]:
             f"{what}: Pallas kernels live in spartan_tpu/kernels/ "
             "(docs/KERNELS.md) — add the kernel there, derive its "
             "grid from the committed Tiling (kernels.registry.derive) "
-            "and route callers through kernels.select"))
+            "and route callers through kernels.registry.select"))
 
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom):
