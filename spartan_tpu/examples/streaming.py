@@ -143,10 +143,10 @@ class OnlineKMeans:
             np.ones((self.k,), self.centers.dtype))
 
     def partial_fit(self, batch: Any) -> DistArray:
-        import jax
         import jax.numpy as jnp
 
         from ..expr.map2 import map2
+        from ..ops import segment
 
         pts = _dist(np.asarray(batch, self.centers.dtype))
         k = self.k
@@ -157,10 +157,8 @@ class OnlineKMeans:
                                      precision="highest")
                   + jnp.sum(centers * centers, axis=1)[None, :])
             assign = jnp.argmin(d2, axis=1)
-            sums = jax.ops.segment_sum(points, assign, num_segments=k)
-            cnt = jax.ops.segment_sum(
-                jnp.ones((points.shape[0],), points.dtype), assign,
-                num_segments=k)
+            sums = segment.segment_sum(points, assign, k)
+            cnt = segment.segment_count(assign, k, points.dtype)
             new_counts = counts + cnt
             lr = (cnt / new_counts)[:, None]
             mean = sums / jnp.maximum(cnt, 1.0)[:, None]

@@ -102,17 +102,14 @@ _GATHER_VMEM = 64 << 20
 GATHER_MAX_COLS = 8 << 20
 
 
-def _gather_parts(x: jax.Array) -> jax.Array:
-    """``x`` padded to whole column windows, each laid out (lane,
-    subrow), as three bf16 parts (3, windows, 128, 128) whose f32 sum is
+def bf16_parts(x: jax.Array) -> list:
+    """float32 ``x`` as three bf16 arrays (hi, mid, lo) whose f32 sum is
     ``x`` bit for bit: each part keeps the top 8 significant bits of
-    what the parts before it left. The bits are cut by masking, not by
-    rounding: XLA may fold an f32 -> bf16 -> f32 round trip away (excess
-    precision), which would leave the lower parts zero."""
-    n = x.shape[0]
-    nw = max(-(-n // CW), 1)
-    rest = jnp.pad(x.astype(jnp.float32), (0, nw * CW - n))
-    rest = rest.reshape(nw, 128, 128).transpose(0, 2, 1)
+    what the parts before it left, so a product of a part with 0 or 1
+    is exact. The bits are cut by masking, not by rounding: XLA may fold
+    an f32 -> bf16 -> f32 round trip away (excess precision), which
+    would leave the lower parts zero."""
+    rest = x.astype(jnp.float32)
     parts = []
     for _ in range(_PARTS):
         top = jax.lax.bitcast_convert_type(
@@ -120,7 +117,17 @@ def _gather_parts(x: jax.Array) -> jax.Array:
             & jnp.uint32(0xFFFF0000), jnp.float32)
         parts.append(top.astype(jnp.bfloat16))   # exact: 8 bits
         rest = rest - top                         # exact: same binade
-    return jnp.stack(parts)
+    return parts
+
+
+def _gather_parts(x: jax.Array) -> jax.Array:
+    """``x`` padded to whole column windows, each laid out (lane,
+    subrow), as its three bf16 parts (3, windows, 128, 128)."""
+    n = x.shape[0]
+    nw = max(-(-n // CW), 1)
+    rest = jnp.pad(x.astype(jnp.float32), (0, nw * CW - n))
+    return jnp.stack(bf16_parts(rest.reshape(nw, 128, 128)
+                                .transpose(0, 2, 1)))
 
 
 def windowed_gather(x: jax.Array, lcols: jax.Array, gwin: jax.Array,

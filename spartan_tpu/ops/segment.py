@@ -3,9 +3,12 @@
 The TPU-native equivalent of the reference's Cython sparse-merge kernel
 (SURVEY.md §2.5: ``spartan/sparse_update.pyx``). Two forms:
 
-* :func:`segment_sum` — ``jax.ops.segment_sum`` (XLA scatter). It
-  measured faster than a blocked one-hot Pallas kernel and a one-hot
-  matmul on v5e (1M x 128, k=64: xla 33ms, onehot 67ms, pallas 71ms).
+* :func:`segment_sum` — one lowering chosen by shape: a one-hot
+  contraction on the MXU for float32 values over few segments
+  (``_ONEHOT_MAX_SEGMENTS``), XLA's scatter otherwise. XLA's scatter
+  applies its updates one after another: inside the plain k-means
+  plan at 1M x 128, k=64 on v5e its two segment-sums took 15.5 of
+  17.3 ms an iteration.
 * :class:`SegmentPlan` — a host-planned layout for the windowed
   sorted-segment and gather kernels (spartan_tpu/kernels/segment.py),
   the SpMV path PageRank runs on one chip.
@@ -13,14 +16,28 @@ The TPU-native equivalent of the reference's Cython sparse-merge kernel
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..kernels.segment import CW as _GATHER_WINDOW
 from ..kernels.segment import GATHER_MAX_COLS as _GATHER_MAX_COLS
+from ..kernels.segment import bf16_parts
+from ..obs.trace import named_scope
+from ..utils import profiling as prof
+
+# The most segments a float32 segment-sum sums as a one-hot product;
+# above it, XLA's scatter. The largest k at which the product won at
+# both widths on v5e, n = 2^20 rows (benchmarks/segment_sweep.py,
+# segment_sweep_tpu.json), ms, scatter / one-hot:
+#   k      16          64          256         1024        4096
+#   d=1    9.20/0.25   9.20/0.23   9.19/0.54   9.20/2.02   7.00/11.80
+#   d=128  7.08/2.19   9.44/2.18   7.08/3.37   7.08/7.17   7.09/20.14
+_ONEHOT_MAX_SEGMENTS = 256
 
 
 def segment_sum(vals: jax.Array, ids: jax.Array, num_segments: int,
@@ -29,9 +46,57 @@ def segment_sum(vals: jax.Array, ids: jax.Array, num_segments: int,
 
     ids outside [0, num_segments) are dropped (XLA segment_sum
     semantics), which the padding paths rely on. ``sorted_ids`` unlocks
-    XLA's sorted-scatter fast path (the SparseDistArray invariant)."""
+    XLA's sorted-scatter fast path (the SparseDistArray invariant).
+
+    float32 ``vals`` over at most ``_ONEHOT_MAX_SEGMENTS`` segments are
+    summed by :func:`_onehot_sum`; everything else by the scatter."""
+    if (vals.dtype == jnp.float32
+            and num_segments <= _ONEHOT_MAX_SEGMENTS):
+        return _onehot_sum(vals, ids, num_segments)
     return jax.ops.segment_sum(vals, ids, num_segments=num_segments,
                                indices_are_sorted=sorted_ids)
+
+
+def _onehot_sum(vals: jax.Array, ids: jax.Array,
+                num_segments: int) -> jax.Array:
+    """``jax.ops.segment_sum`` as ``onehot(ids)^T @ vals`` on the MXU.
+
+    The contraction runs over the rows of both operands, so no
+    transpose is materialised; an id outside [0, num_segments) matches
+    no column and is dropped. Every product is exact: the one-hot is
+    exact in bf16 and the values go in as their three bf16 parts
+    (``kernels/segment.bf16_parts``), one product each, summed in f32;
+    only the order of the additions differs from the scatter's. XLA's
+    HIGHEST precision does not do here: on v5e its means of 1M x 128
+    points into 64 segments erred 7.8e-5 (median over segments, against
+    float64), the scatter's 2.5e-5, the three parts' 1.8e-6.
+
+    A non-finite value always leaves a NaN in the small result (``0 x
+    inf`` in the other segments, or ``inf - inf`` in the split into
+    parts); finite values give none unless sums overflow to both
+    infinities. A NaN in the result sends the call to the scatter, so
+    non-finite inputs get its answer bit for bit. On finite data the
+    check is one reduction over the (num_segments, ...) result. Under
+    ``vmap`` the ``cond`` becomes a select that runs both branches.
+    The fallback's ops carry the scope ``segment_sum_nonfinite``.
+    Counted once a lowering as ``segment_sum_onehot``."""
+    prof.count("segment_sum_onehot")
+    vals, ids = jnp.asarray(vals), jnp.asarray(ids)
+    onehot = (ids[:, None] == jnp.arange(num_segments)).astype(
+        jnp.bfloat16)
+    flat = vals.reshape(vals.shape[0], math.prod(vals.shape[1:]))
+    out = None
+    for part in reversed(bf16_parts(flat)):   # lo and mid first
+        p = lax.dot_general(onehot, part, (((0,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        out = p if out is None else out + p
+    out = out.reshape((num_segments,) + vals.shape[1:])
+
+    def nonfinite():
+        with named_scope("segment_sum_nonfinite"):
+            return jax.ops.segment_sum(vals, ids, num_segments)
+
+    return lax.cond(jnp.isnan(out).any(), nonfinite, lambda: out)
 
 
 def segment_count(ids: jax.Array, num_segments: int,

@@ -336,10 +336,15 @@ def test_profile_schema_roundtrip_versions(tmp_path):
 # -- obs thread-safety under serving (ISSUE-11 satellite) ----------------
 
 
-def test_obs_thread_safety_under_concurrent_submitters():
+def test_obs_thread_safety_under_concurrent_submitters(mesh1d):
     """Trace ring + flight recorder + sampled profiler hit by N
     concurrent evaluate_async submitters: no deadlock, no torn
-    records, results bit-equal to serial."""
+    records, results bit-equal to serial on the same mesh (the mesh is
+    thread-local: a client thread enters the one the reference ran on,
+    since a reduction's partition, and so its order of additions,
+    follows the mesh)."""
+    from spartan_tpu.parallel import mesh as mesh_mod
+
     rng = np.random.RandomState(5)
     pts = st.from_numpy(rng.rand(128, 16).astype(np.float32))
     c0 = st.as_expr(rng.rand(8, 16).astype(np.float32)).evaluate()
@@ -354,9 +359,10 @@ def test_obs_thread_safety_under_concurrent_submitters():
 
     def client(i):
         try:
-            for j in range(per_thread):
-                fut = eng.submit(_kstep(pts, c0))
-                results[i][j] = np.asarray(fut.glom())
+            with mesh_mod.use_mesh(mesh1d):
+                for j in range(per_thread):
+                    fut = eng.submit(_kstep(pts, c0))
+                    results[i][j] = np.asarray(fut.glom())
         except Exception as e:  # noqa: BLE001 - collected for assert
             errors.append(e)
 

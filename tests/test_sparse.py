@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from spartan_tpu.array.sparse import SparseDistArray
+from spartan_tpu.ops import segment as segment_mod
 from spartan_tpu.ops.segment import segment_count, segment_sum
 
 
@@ -19,37 +20,120 @@ def _random_sparse(n=20, m=16, density=0.2, seed=0):
     return dense.astype(np.float32)
 
 
-def _uniform_stream(rng):
+def _uniform_stream(rng, k):
     return (rng.rand(500, 8).astype(np.float32),
-            rng.randint(0, 16, 500))
+            rng.randint(0, k, 500))
 
 
-def _int_valued_oob_stream(rng):
+def _int_valued_oob_stream(rng, k):
     """Integer-valued floats (every sum exact in f32) with ids out of
     range at both ends, which the merge must drop."""
     return (rng.randint(-8, 9, (1000, 16)).astype(np.float32),
-            rng.randint(-2, 20, 1000))
+            rng.randint(-2, k + 4, 1000))
 
 
-@pytest.mark.parametrize("stream", [_uniform_stream,
-                                    _int_valued_oob_stream],
-                         ids=["uniform", "int_valued_oob"])
-def test_segment_sum_impls(stream):
+def _add_at(vals, ids, k):
+    keep = (ids >= 0) & (ids < k)
+    expect = np.zeros((k,) + vals.shape[1:], vals.dtype)
+    np.add.at(expect, ids[keep], vals[keep])
+    return expect
+
+
+_ABOVE = segment_mod._ONEHOT_MAX_SEGMENTS + 1
+
+
+def _impl_case(stream, k, ndim):
+    name = "uniform" if stream is _uniform_stream else "int_valued_oob"
+    return pytest.param(stream, k, ndim, id=(
+        name if (k, ndim) == (16, 2) else
+        f"{name}-{ndim}d-{'above' if k == _ABOVE else k}"))
+
+
+@pytest.mark.parametrize("stream,k,ndim", [
+    _impl_case(stream, k, ndim)
+    for stream in (_uniform_stream, _int_valued_oob_stream)
+    for k in (1, 16, segment_mod._ONEHOT_MAX_SEGMENTS, _ABOVE)
+    for ndim in (2, 1)])
+def test_segment_sum_impls(stream, k, ndim):
+    """Both lowerings, the one-hot product (k up to the bound) and the
+    scatter (above it), against np.add.at."""
     import jax.numpy as jnp
 
-    vals, ids = stream(np.random.RandomState(0))
-    keep = (ids >= 0) & (ids < 16)
-    expect = np.zeros((16, vals.shape[1]), np.float32)
-    np.add.at(expect, ids[keep], vals[keep])
-    out = np.asarray(segment_sum(jnp.asarray(vals), jnp.asarray(ids), 16))
+    vals, ids = stream(np.random.RandomState(0), k)
+    if ndim == 1:
+        vals = vals[:, 0]
+    expect = _add_at(vals, ids, k)
+    out = np.asarray(segment_sum(jnp.asarray(vals), jnp.asarray(ids), k))
     if stream is _int_valued_oob_stream:
         np.testing.assert_array_equal(out.view(np.uint32),
                                       expect.view(np.uint32))
     else:
         np.testing.assert_allclose(out, expect, rtol=1e-5)
-    cnt = np.asarray(segment_count(jnp.asarray(ids), 16))
+    keep = (ids >= 0) & (ids < k)
+    cnt = np.asarray(segment_count(jnp.asarray(ids), k))
     np.testing.assert_array_equal(cnt, np.bincount(ids[keep],
-                                                   minlength=16))
+                                                   minlength=k))
+
+
+def _nonfinite_rows():
+    vals = np.arange(40, dtype=np.float32).reshape(10, 4)
+    vals[1, 0], vals[4, 1], vals[6, 2] = np.inf, -np.inf, np.nan
+    vals[7, 0] = np.inf            # +inf and -inf meet in segment 1
+    vals[9, 1] = -np.inf
+    return vals, np.array([0, 1, 2, 3, 0, 1, 2, 3, 0, 1]), 4
+
+
+def _nonfinite_oob():
+    vals = np.ones((6, 3), np.float32)
+    vals[2, 1] = np.inf
+    return vals, np.array([0, 1, 5, 2, 1, -1]), 3
+
+
+def _nonfinite_one_segment():
+    vals = np.arange(12, dtype=np.float32).reshape(6, 2)
+    vals[3, 0] = np.inf
+    return vals, np.zeros(6, np.int64), 1
+
+
+@pytest.mark.parametrize("case", [_nonfinite_rows, _nonfinite_oob,
+                                  _nonfinite_one_segment],
+                         ids=["inf_nan_rows", "oob_inf", "k1_inf"])
+def test_segment_sum_nonfinite_exact(case):
+    """Non-finite values on the one-hot side give the scatter's answer
+    bit for bit: infinities, NaNs, an infinity on a dropped id, and one
+    segment."""
+    import jax.numpy as jnp
+
+    vals, ids, k = case()
+    assert k <= segment_mod._ONEHOT_MAX_SEGMENTS
+    out = np.asarray(segment_sum(jnp.asarray(vals), jnp.asarray(ids), k))
+    np.testing.assert_array_equal(out.view(np.uint32),
+                                  _add_at(vals, ids, k).view(np.uint32))
+
+
+@pytest.mark.parametrize("k,dtype,onehot", [
+    (16, np.float32, 1), (_ABOVE, np.float32, 0), (16, np.int32, 0)],
+    ids=["f32_few", "f32_above", "int32"])
+def test_segment_sum_lowering_by_shape(k, dtype, onehot):
+    """float32 values over at most ``_ONEHOT_MAX_SEGMENTS`` segments
+    lower to the one-hot product, counted once a lowering; more
+    segments and integer values keep the scatter."""
+    import jax
+    import jax.numpy as jnp
+
+    from spartan_tpu.utils import profiling as prof
+
+    rng = np.random.RandomState(1)
+    vals = rng.randint(-5, 6, (300, 4)).astype(dtype)
+    ids = rng.randint(-1, k + 1, 300)
+    fn = jax.jit(lambda v, i: segment_sum(v, i, k))
+    prof.reset_counters()
+    txt = fn.lower(jnp.asarray(vals), jnp.asarray(ids)).as_text()
+    assert prof.counters().get("segment_sum_onehot", 0) == onehot
+    assert ("dot_general" in txt) == bool(onehot)
+    out = np.asarray(fn(jnp.asarray(vals), jnp.asarray(ids)))
+    assert out.dtype == dtype
+    np.testing.assert_array_equal(out, _add_at(vals, ids, k))
 
 
 def test_segment_sum_out_of_range_dropped():

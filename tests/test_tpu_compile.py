@@ -196,19 +196,74 @@ def _no_kernel(txt: str) -> None:
     assert "tpu_custom_call" not in txt
 
 
+def _main_path_scatters(txt: str) -> list:
+    """The scatters of a compiled program outside the one-hot
+    segment-sum's fallback for non-finite input (``ops/segment``),
+    which a finite run never takes."""
+    return [ln for ln in txt.splitlines() if " scatter(" in ln
+            and "/segment_sum_nonfinite/" not in ln]
+
+
 @pytest.mark.parametrize("shape", [(1 << 20, 64), (1 << 20,)])
 def test_segment_sum(topo, mosaic, shape):
     """The segment-sum merge (naive Bayes' class sums, the sparse
-    paths' todense and row sums): XLA's scatter over 1M entries sharded
-    across four chips into 64 segments."""
+    paths' todense and row sums) over 1M entries sharded across four
+    chips into 64 segments: a one-hot product on each chip and a
+    cross-chip sum, no scatter on the finite path."""
     from spartan_tpu.ops.segment import segment_sum
 
     mesh = _mesh(topo, (4, 1))
     rows = P(tiling_mod.AXIS_ROW, *([None] * (len(shape) - 1)))
-    _no_kernel(_compiled_text(
+    txt = _compiled_text(
         segment_sum, _sds(shape, F32, mesh, rows),
         _sds((shape[0],), I32, mesh, P(tiling_mod.AXIS_ROW)),
-        num_segments=64))
+        num_segments=64)
+    _no_kernel(txt)
+    assert "all-reduce" in txt
+    assert not _main_path_scatters(txt)
+
+
+def test_segment_sum_many_segments_scatters(topo, mosaic):
+    """Above ``_ONEHOT_MAX_SEGMENTS`` (here 2^20 segments, the sparse
+    paths' row sums) the merge stays XLA's scatter."""
+    from spartan_tpu.ops.segment import segment_sum
+
+    mesh = _mesh(topo, (1, 1))
+    txt = _compiled_text(segment_sum, _sds((1 << 24,), F32, mesh),
+                         _sds((1 << 24,), I32, mesh),
+                         num_segments=1 << 20, sorted_ids=True)
+    _no_kernel(txt)
+    assert _main_path_scatters(txt)
+    assert "convolution(" not in txt
+
+
+def test_kmeans_plain_step(topo, mosaic):
+    """The fit20_plain cell's plan, ``examples.kmeans.kmeans_step`` at
+    1M x 128, k=64 on one chip: both segment-sums are one-hot products,
+    no scatter on the finite path and no Pallas kernel."""
+    from spartan_tpu.examples.kmeans import kmeans_step
+    from spartan_tpu.expr.base import ScalarExpr
+    from spartan_tpu.parallel import mesh as mesh_mod
+
+    mesh = _mesh(topo, (1, 1))
+    rows = P(tiling_mod.AXIS_ROW, None)
+    pts = _val((1_000_000, 128), F32, rows)
+    cen = _val((64, 128), F32, P(None, None))
+    with mesh_mod.use_mesh(mesh):
+        step = kmeans_step(pts, cen, 64)
+        env, todo = {}, [step]
+        while todo:   # the scalar leaves (the counts' floor of 1)
+            e = todo.pop()
+            if isinstance(e, ScalarExpr):
+                env[e._id] = e.pyvalue
+            todo.extend(e.children())
+        txt = _compiled_text(
+            lambda p, c: step.lower({**env, pts._id: p, cen._id: c}),
+            _sds((1_000_000, 128), F32, mesh, rows),
+            _sds((64, 128), F32, mesh))
+    _no_kernel(txt)
+    assert not _main_path_scatters(txt)
+    assert txt.count("conditional(") == 2
 
 
 @pytest.mark.parametrize("shape", [(2, 2), (4, 1)], ids=["2x2", "4x1"])
