@@ -337,6 +337,8 @@ class LoopItemExpr(Expr):
             obs_trace.loop_steps_begin(label)  # anchor step 0's span
         if self.loop.health:
             obs_numerics.loop_health_begin(label)  # fresh series
+        if isinstance(static_n, int):  # see loop()'s docstring
+            prof.count("loop_iterations", static_n)
         with prof.span("loop", loop=label, n=static_n,
                        carries=len(self.loop.init)):
             siblings = getattr(self.loop, "_items", None)
@@ -413,6 +415,13 @@ def loop(n_iters: Any, body_fn: Callable, *init: Any,
     or, with ``stall_tol > 0``, when the update norm drops below the
     tolerance (convergence). All three are part of the loop's
     structural signature, so toggling recompiles.
+
+    Each time a result of ``loop`` is forced (``evaluate``/``glom``)
+    and its ``n_iters`` is a Python int, ``n_iters`` is added to the
+    metrics registry's ``loop_iterations`` counter (``evaluations``
+    counts the dispatch); a loop lowered inside a larger expression
+    adds nothing. An ``early_exit`` loop adds its ``n_iters`` too: the
+    most it may run, not the iterations it ran.
 
     ``checkpoint_every`` / ``checkpoint_path`` / ``resume``
     (resilience/loop_ckpt.py): split the loop into segments of

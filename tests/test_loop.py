@@ -192,3 +192,29 @@ def test_multi_carry_single_program():
         a, b = b, a + b
     np.testing.assert_allclose(ga, a)
     np.testing.assert_allclose(gb, b)
+
+
+def test_loop_iterations_counter():
+    """Each evaluation adds the static count to ``loop_iterations``
+    once, however many carries the loop has; an early-exit loop adds
+    its count too, the most it may run; a forced result read again
+    adds nothing."""
+    from spartan_tpu.utils import profiling as prof
+
+    def added(fn):
+        before = prof.counters()
+        fn()
+        after = prof.counters()
+        return {k: after.get(k, 0) - before.get(k, 0)
+                for k in ("loop_iterations", "evaluations")}
+
+    out = st.loop(7, lambda c: c + 1.0, 0.0)
+    assert added(out.glom) == {"loop_iterations": 7, "evaluations": 1}
+    assert added(out.glom)["loop_iterations"] == 0
+    ea = st.from_numpy(np.ones((4, 4), np.float32))
+    fa, fb = st.loop(6, lambda a, b: (b, a + b), ea, ea)
+    assert added(lambda: (fa.glom(), fb.glom())) == {
+        "loop_iterations": 6, "evaluations": 1}
+    stop = st.loop(50, lambda c: c * 1e30, st.from_numpy(
+        np.ones(4, np.float32)), early_exit=True)
+    assert added(stop.glom)["loop_iterations"] == 50

@@ -266,6 +266,59 @@ def test_kmeans_plain_step(topo, mosaic):
     assert txt.count("conditional(") == 2
 
 
+def _computation(txt: str, name: str) -> str:
+    """The body of HLO computation ``name`` in a compiled program."""
+    m = re.search(rf"^%{re.escape(name)} \(.*?^}}", txt, re.M | re.S)
+    assert m, name
+    return m.group(0)
+
+
+def test_logreg_fit_loop(topo, mosaic):
+    """The logreg_10m_d32.fit100 cell's program, a 100-iteration
+    ``st.loop`` of ``examples.regression.logistic_grad`` steps at
+    10M x 32 on one chip: X keeps its unpadded column-major layout, no
+    X-sized temporary or convert, and each iteration reads X in two
+    fusions (X·w, then Xᵀr)."""
+    import spartan_tpu as st
+    from spartan_tpu.examples.regression import logistic_grad
+    from spartan_tpu.expr.base import ScalarExpr
+    from spartan_tpu.parallel import mesh as mesh_mod
+
+    n, d = 10_000_000, 32
+    mesh = _mesh(topo, (1, 1))
+    rows2, rows1 = P(tiling_mod.AXIS_ROW, None), P(tiling_mod.AXIS_ROW)
+    x, y, w0 = (_val((n, d), F32, rows2), _val((n,), F32, rows1),
+                _val((d,), F32, P(None)))
+    with mesh_mod.use_mesh(mesh):
+        out = st.loop(100, lambda w: w - 1.0 * logistic_grad(x, y, w), w0)
+        env, todo = {}, [out]
+        while todo:   # the scalar leaves: the count, lr, the link's 1s
+            e = todo.pop()
+            if isinstance(e, ScalarExpr):
+                env[e._id] = e.pyvalue
+            todo.extend(e.children())
+        compiled = jax.jit(
+            lambda a, b, c: out.lower({**env, x._id: a, y._id: b,
+                                       w0._id: c})
+        ).lower(_sds((n, d), F32, mesh, rows2), _sds((n,), F32, mesh, rows1),
+                _sds((d,), F32, mesh)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 1.33e9   # 4nd + 4n + 4d
+    assert mem.temp_size_in_bytes < 64 << 20
+    txt = compiled.as_text()
+    _no_kernel(txt)
+    assert not [ln for ln in txt.splitlines()
+                if " convert(" in ln and f"[{n},{d}]" in ln]
+    body = _computation(txt, re.search(r" while\(.*?body=%([\w.\-]+)",
+                                       txt).group(1))
+    xs = re.findall(rf"%([\w.\-]+) = f32\[{n},{d}\]\S* get-tuple-element",
+                    body)
+    assert len(xs) == 1, xs
+    readers = [ln for ln in body.splitlines() if " fusion(" in ln
+               and re.search(rf"%{re.escape(xs[0])}[,)]", ln)]
+    assert len(readers) == 2, readers
+
+
 @pytest.mark.parametrize("shape", [(2, 2), (4, 1)], ids=["2x2", "4x1"])
 def test_sort_exchange_four_chips(topo, mosaic, shape):
     """A 1-D 16M-element sample sort on the two meshes the four-chip
